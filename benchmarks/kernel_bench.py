@@ -79,6 +79,9 @@ def main(smoke: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes only (CI kernel-regression gate)")

@@ -159,6 +159,9 @@ def main(base_quant=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--base-quant", default=None, choices=("nf4", "int8"),
                     help="store the merged frozen weights blockwise "

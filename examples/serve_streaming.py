@@ -121,6 +121,9 @@ def main(use_asyncio: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--asyncio", action="store_true",
                     help="drive the front end on an asyncio event loop "

@@ -414,8 +414,8 @@ def _build_flash_fwd():
     ])
 
 
-# operand 0 is the int32 per-slot lengths array; outputs match q (op 1)
-@register_kernel("flash_decode", out_dtype_like=1)
+# the int32 per-slot lengths ride as scalar prefetch; outputs match q
+@register_kernel("flash_decode")
 def _build_flash_decode():
     from repro.kernels.flash_attention import flash_decode_attention
 
@@ -473,10 +473,10 @@ def _build_paged_decode():
 
 
 # Dequant-in-VMEM paged decode (kv_quant): operands after the two int32
-# scalar-prefetch arrays are q (0), packed K codes, K scales, packed V
-# codes, V scales [, the (1, 16) nf4 codebook] — outputs match q, the
-# code+scale pools gather through the SAME table index maps as the fp
-# kernel, and the online-softmax scratch contract is unchanged.
+# scalar-prefetch arrays are the query part(s) (nf4: its two head_dim
+# halves), packed K codes, K scales, packed V codes, V scales — outputs
+# match q, the code+scale pools gather through the SAME table index maps
+# as the fp kernel, and the online-softmax scratch contract is unchanged.
 @register_kernel("paged_decode_quant")
 def _build_paged_decode_quant():
     from repro.core.quantize import quantize_kv
@@ -564,9 +564,10 @@ def _build_quanta_linear():
 
 
 # operands: x (0), packed codes (uint8/int8), per-block scales, then the
-# nf4 codebook / normalizers — outputs match x, accumulation is fp32
-# inside the dot (no scratch: one grid step owns its full output block)
-@register_kernel("quantized_matmul")
+# normalizers — outputs match x, accumulation is fp32 inside the dot.
+# The one scratch buffer is the dequantized weight tile, held in the
+# activation dtype (the dot's operand), not an accumulator.
+@register_kernel("quantized_matmul", fp32_scratch=False)
 def _build_quantized_matmul():
     from repro.core.quantize import quantize_linear
     from repro.kernels.quantized_matmul import quantized_matmul

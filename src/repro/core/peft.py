@@ -340,8 +340,13 @@ def attach(
         adapter = _make_adapter(k, w, cfg)
         if cfg.method == "quanta" and not cfg.fold:
             # fold-free (Eq. 8): stamp the frozen copy S onto the adapter
-            # instead of folding it into the base weight
-            adapter = dataclasses.replace(adapter, frozen=adapter.tensors)
+            # instead of folding it into the base weight.  A copy, not the
+            # same buffers: a train step that donates its state would
+            # otherwise donate each of them twice.
+            adapter = dataclasses.replace(
+                adapter,
+                frozen=jax.tree_util.tree_map(jnp.copy, adapter.tensors),
+            )
         _set_path(peft, path, adapter)
         stacked = w.ndim == 3
         d_in, d_out = w.shape[-2], w.shape[-1]

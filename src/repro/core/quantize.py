@@ -15,10 +15,11 @@ full-precision adapters).  This module provides:
   under ``jax.lax.scan`` exactly like the dense ``(L, d_in, d_out)``
   weights it replaces.
 * :func:`quantize_linear` / :func:`dequantize` — the lossy encode and
-  the exact decode.  ``dequant_values`` is THE single elementwise
-  dequantization both the reference matmul and the Pallas kernel tile
-  use — the kernel's bitwise-equality gate (tests/test_quantize.py)
-  only holds because there is one implementation to agree with.
+  the exact decode.  :func:`code_planes` and :func:`scale_values` are
+  THE elementwise dequantization: ``dequant_values`` (the reference)
+  and the Pallas kernels build on them, so the kernel-vs-reference
+  equality gates (tests/test_quantize.py) have one implementation to
+  agree with.
 * :func:`quantize_params` — quantize every projection leaf a model
   applies through ``peft_linear`` (``QUANT_TARGETS``); embeddings, the
   LM head, norms, biases, convs, and raw-matmul projections (Mamba2's
@@ -40,7 +41,8 @@ adapter composition is the same contract as ``quanta_linear_fused``
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +56,7 @@ __all__ = [
     "blockwise_absmax",
     "blockwise_round",
     "blockwise_scales",
+    "code_planes",
     "dequant_values",
     "dequantize",
     "ensure_dense",
@@ -65,6 +68,7 @@ __all__ = [
     "quantize_linear",
     "quantize_params",
     "quantized_nbytes",
+    "scale_values",
 ]
 
 # The 16-level NF4 codebook (QLoRA, Dettmers et al. 2023): quantiles of a
@@ -173,7 +177,8 @@ class QuantizedLinear:
     dense weight they replace):
 
     * ``packed`` — NF4: ``uint8 (..., d_in//2, d_out)``, two 4-bit codes
-      per byte along ``d_in`` (high nibble = even row, low = odd row);
+      per byte along ``d_in`` (high nibble = row ``i``, low nibble = row
+      ``i + d_in // 2``);
       int8: ``int8 (..., d_in, d_out)``.
     * ``scales`` — ``(..., ceil(d_in/block_size), d_out)`` per-block
       absmax scales (fp32 or fp16).
@@ -264,12 +269,11 @@ def quantize_linear(
             )
         scales = blockwise_scales(w32, block_size, axis=-2, levels=1.0)
         v = w32 / expand_scales(scales, block_size, d_in, axis=-2)
-        codes = jnp.searchsorted(
-            jnp.asarray(_NF4_BOUNDS), jnp.clip(v, -1.0, 1.0), side="right"
+        codes = _nf4_encode(v)
+        half = d_in // 2
+        packed = (
+            (codes[..., :half, :] << 4) | codes[..., half:, :]
         ).astype(jnp.uint8)
-        even = codes[..., 0::2, :]
-        odd = codes[..., 1::2, :]
-        packed = ((even << 4) | odd).astype(jnp.uint8)
     else:
         scales = blockwise_scales(w32, block_size, axis=-2, levels=127.0)
         packed = blockwise_round(
@@ -282,6 +286,60 @@ def quantize_linear(
     )
 
 
+def _nf4_encode(v: jnp.ndarray) -> jnp.ndarray:
+    """Nearest NF4 code (uint8) of each value in ``[-1, 1]``-scaled
+    units: the number of decision bounds at or below it, which is what
+    ``searchsorted(_NF4_BOUNDS, v, side="right")`` returns.  Summed
+    elementwise, so no temporary is larger than ``v`` — a searchsorted
+    over a layer-stacked KV wave or weight needs several times its size
+    in device memory."""
+    v = jnp.clip(v, -1.0, 1.0)
+    codes = jnp.zeros(v.shape, jnp.uint8)
+    for b in _NF4_BOUNDS.tolist():
+        codes = codes + (v >= b).astype(jnp.uint8)
+    return codes
+
+
+def _nf4_lookup(codes: jnp.ndarray) -> jnp.ndarray:
+    """fp32 :data:`NF4_CODEBOOK` values of integer NF4 codes.
+
+    A 16-way select instead of a gather: the same values bit for bit,
+    and it lowers inside a TPU kernel, where Mosaic supports no general
+    gather and a kernel body cannot capture an array constant."""
+    vals = jnp.zeros(codes.shape, jnp.float32)
+    for c, v in enumerate(NF4_CODEBOOK.tolist()):
+        vals = jnp.where(codes == c, jnp.float32(v), vals)
+    return vals
+
+
+def code_planes(packed: jnp.ndarray, fmt: str) -> List[jnp.ndarray]:
+    """fp32 values, before scaling, of the code planes of a packed
+    array: NF4 gives two, the high nibbles (the first half of the packed
+    axis' elements) then the low nibbles (the second half); int8 gives
+    the codes themselves.  Concatenating the planes along the packed
+    axis restores the natural element order."""
+    codes = packed.astype(jnp.int32)
+    if fmt == "nf4":
+        return [_nf4_lookup(codes >> 4), _nf4_lookup(codes & 0xF)]
+    if fmt == "int8":
+        return [codes.astype(jnp.float32)]
+    raise ValueError(f"unknown quantization format {fmt!r}")
+
+
+def scale_values(vals: jnp.ndarray, scales: jnp.ndarray,
+                 row_norm: Optional[jnp.ndarray] = None,
+                 col_norm: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``vals * scales [* row_norm] [* col_norm]`` in fp32, operands
+    already broadcast to ``vals``' rows — the elementwise tail of every
+    dequantization, shared by the reference and the kernel bodies."""
+    w = vals * scales.astype(jnp.float32)
+    if row_norm is not None:
+        w = w * row_norm.astype(jnp.float32)
+    if col_norm is not None:
+        w = w * col_norm.astype(jnp.float32)
+    return w
+
+
 def dequant_values(
     packed: jnp.ndarray,
     scales: jnp.ndarray,
@@ -291,44 +349,21 @@ def dequant_values(
     fmt: str,
     block_size: int,
     d_in: int,
-    codebook: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Elementwise fp32 dequantization of (a tile of) a quantized weight.
+    """Elementwise fp32 dequantization of a quantized weight.
 
-    THE single implementation shared by the reference matmul and the
-    Pallas kernel body (``kernels.quantized_matmul``): the kernel's
-    bitwise-equality gate holds because a column tile of this function
-    equals this function of the column tile — every op here is
-    elementwise or a row-block broadcast along the un-tiled ``d_in``
-    axis, which the kernel never splits.
-
-    ``codebook`` defaults to :data:`NF4_CODEBOOK`; the Pallas kernel
-    passes its VMEM-resident copy (a kernel body cannot capture host
-    constants) holding the exact same 16 values.
+    The reference the Pallas kernels are gated against: they dequantize
+    row strips in VMEM through the same :func:`code_planes` /
+    :func:`scale_values`, so every element is computed by the same fp32
+    operations in the same order.
     """
-    if fmt == "nf4":
-        hi = (packed >> 4).astype(jnp.int32)
-        lo = (packed & 0xF).astype(jnp.int32)
-        # interleave: row 2k from the high nibble, row 2k+1 from the low
-        codes = jnp.stack([hi, lo], axis=-2).reshape(
-            packed.shape[:-2] + (d_in, packed.shape[-1])
-        )
-        if codebook is None:
-            codebook = jnp.asarray(NF4_CODEBOOK)
-        vals = codebook[codes]
-    elif fmt == "int8":
-        vals = packed.astype(jnp.float32)
-    else:
-        raise ValueError(f"unknown quantization format {fmt!r}")
-    s = expand_scales(
-        scales.astype(jnp.float32), block_size, d_in, axis=-2
+    vals = jnp.concatenate(code_planes(packed, fmt), axis=-2)
+    s = expand_scales(scales, block_size, d_in, axis=-2)
+    return scale_values(
+        vals, s,
+        None if row_norm is None else row_norm[..., :, None],
+        None if col_norm is None else col_norm[..., None, :],
     )
-    w = vals * s
-    if row_norm is not None:
-        w = w * row_norm.astype(jnp.float32)[..., :, None]
-    if col_norm is not None:
-        w = w * col_norm.astype(jnp.float32)[..., None, :]
-    return w
 
 
 def dequantize(qw: QuantizedLinear, dtype: Any = None) -> jnp.ndarray:
@@ -366,8 +401,9 @@ def quantize_kv(
     paged-vs-dense-fake-quantized equality gate rests on).
 
     Returns ``(codes, scales)``: NF4 packs two 4-bit codes per byte
-    along the last axis (``uint8 (..., d//2)``, high nibble = even
-    element — the same nibble convention as :class:`QuantizedLinear`);
+    along the last axis (``uint8 (..., d//2)``, high nibble = element
+    ``i``, low nibble = element ``i + d // 2`` — the same nibble
+    convention as :class:`QuantizedLinear`);
     int8 keeps ``int8 (..., d)``.  Scales are fp32
     ``(..., ceil(d/block_size))``.
     """
@@ -383,10 +419,9 @@ def quantize_kv(
             )
         scales = blockwise_scales(x32, block_size, axis=-1, levels=1.0)
         v = x32 / expand_scales(scales, block_size, d, axis=-1)
-        codes = jnp.searchsorted(
-            jnp.asarray(_NF4_BOUNDS), jnp.clip(v, -1.0, 1.0), side="right"
-        ).astype(jnp.uint8)
-        packed = ((codes[..., 0::2] << 4) | codes[..., 1::2]).astype(
+        codes = _nf4_encode(v)
+        half = d // 2
+        packed = ((codes[..., :half] << 4) | codes[..., half:]).astype(
             jnp.uint8
         )
     else:
@@ -404,7 +439,6 @@ def kv_dequant_values(
     fmt: str,
     block_size: int,
     d: int,
-    codebook: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Elementwise fp32 dequantization of KV rows quantized along the
     last axis — :func:`dequant_values` applied through a trailing
@@ -413,7 +447,7 @@ def kv_dequant_values(
     (the ISSUE's "same ``dequant_values`` feeds both paths" gate)."""
     return dequant_values(
         codes[..., None], scales[..., None], None, None,
-        fmt=fmt, block_size=block_size, d_in=d, codebook=codebook,
+        fmt=fmt, block_size=block_size, d_in=d,
     )[..., 0]
 
 
@@ -458,9 +492,11 @@ def matmul_ref(x: jnp.ndarray, qw: QuantizedLinear) -> jnp.ndarray:
 def base_matmul(x: jnp.ndarray, w, backend: str = "reference") -> jnp.ndarray:
     """The frozen-base linear under every adapter: ``x @ w`` verbatim for
     dense weights (bit-identical to what the models always ran), fused
-    dequant-matmul for :class:`QuantizedLinear` (``backend="pallas"``
-    routes through the Pallas kernel, which the VMEM gate may still fall
-    back to the — bitwise identical — reference)."""
+    dequant-matmul for :class:`QuantizedLinear`: ``backend="pallas"``
+    runs a 2-D weight through the Pallas kernel (which raises rather
+    than fall back where no tile fits VMEM); the reference backend and
+    layer-stacked (3-D) weights, which no kernel serves, take
+    :func:`matmul_ref`."""
     if isinstance(w, QuantizedLinear):
         if backend == "pallas" and w.ndim == 2:
             # deferred import: kernels.quantized_matmul imports the
@@ -475,6 +511,23 @@ def base_matmul(x: jnp.ndarray, w, backend: str = "reference") -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Whole-tree quantization
 # ---------------------------------------------------------------------------
+
+@functools.partial(
+    jax.jit, static_argnames=("fmt", "block_size", "normalize", "scale_dtype")
+)
+def _quantize_leaf(w, fmt, block_size, normalize, scale_dtype):
+    # One layer at a time (a 2-D weight is a stack of one): quantizing a
+    # whole (L, d_in, d_out) stack at once holds full-stack fp32
+    # temporaries — 2.1 GB each for minicpm-2b's MLP stacks, beside the
+    # bf16 model on one device.
+    quant = functools.partial(quantize_linear, fmt=fmt,
+                              block_size=block_size, normalize=normalize,
+                              scale_dtype=scale_dtype)
+    q = jax.lax.map(quant, w.reshape((-1,) + w.shape[-2:]))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape(w.shape[:-2] + a.shape[1:]), q
+    )
+
 
 def quantize_params(
     params: Dict[str, Any],
@@ -502,8 +555,8 @@ def quantize_params(
             elif isinstance(val, QuantizedLinear):
                 out[key] = val
             elif key in targets and getattr(val, "ndim", 0) in (2, 3):
-                out[key] = quantize_linear(
-                    val, fmt, block_size=block_size, normalize=normalize,
+                out[key] = _quantize_leaf(
+                    val, fmt=fmt, block_size=block_size, normalize=normalize,
                     scale_dtype=scale_dtype,
                 )
             else:

@@ -57,14 +57,22 @@ def _kernel(ids_ref, x_ref, a_ref, b_ref, *rest, scale: float,
     else:
         (o_ref,) = rest
     h = x_ref[0]                                   # (S, d_in), x dtype
-    # mirror LoraAdapter.delta's numerics exactly: the factored matmuls
-    # run in the adapter dtype, scale multiplies the product, and the
-    # delta is cast back to the activation dtype before the base add
-    za = jnp.dot(h.astype(a_ref.dtype), a_ref[0])  # (S, r)
-    d = (scale * jnp.dot(za, b_ref[0])).astype(h.dtype)
+    # mirror LoraAdapter.delta's numerics: the factored matmuls run in
+    # the adapter dtype, scale multiplies the product, and the delta is
+    # cast back to the activation dtype before the base add.  Every dot
+    # accumulates in fp32 (Mosaic has no sub-32-bit accumulator) and is
+    # rounded to its operand dtype, as XLA rounds ``a @ b``.
+    za = _dot(h.astype(a_ref.dtype), a_ref[0])     # (S, r)
+    d = (scale * _dot(za, b_ref[0])).astype(h.dtype)
     if fuse_base:
-        d = jnp.dot(h, w_ref[...]) + d
+        d = _dot(h, w_ref[...]) + d
     o_ref[0] = d
+
+
+def _dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    ).astype(a.dtype)
 
 
 def banked_vmem_ok(seq: int, d_in: int, d_out: int, rank: int,
@@ -120,6 +128,7 @@ def _call(x, a, b, ids, w, *, scale: float, block_cols: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, seq, d_out + pad), x.dtype),
         interpret=interpret,
+        name="banked_lora_linear" if fuse_base else "banked_lora_delta",
     )(*operands)
     return out[:, :, :d_out] if pad else out
 

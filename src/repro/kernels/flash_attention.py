@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantize import NF4_CODEBOOK, kv_dequant_values
+from repro.core.quantize import code_planes
 from repro.kernels.dispatch import MASK_VALUE, masked_softmax, resolve_interpret
 
 __all__ = [
@@ -144,9 +144,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when((j >= j_lo) & (j <= j_hi))
     def _step():
-        q = q_ref[0, :, 0, :]                              # (bq, hd)
-        k = k_ref[0, :, 0, :]                              # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                                    # (bq, hd)
+        k = k_ref[0, 0]                                    # (bk, hd)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -173,7 +173,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(j == n_k - 1)
     def _finalize():
         denom = l_ref[:, :1]
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc_ref[...] / jnp.where(denom == 0.0, 1.0, denom)
         ).astype(o_ref.dtype)
 
@@ -186,23 +186,26 @@ def _flash_forward(q, k, v, *, window, scale, block_q, block_k, interpret):
     bk = min(block_k, s)
     pad_q = (-s) % bq
     pad_k = (-s) % bk
+    # Head-major views: a block then spans (rows, hd), the two tiled
+    # dims Mosaic lays out in (8, 128) tiles — a size-1 block over the
+    # head axis of the (B, S, H, hd) layout is not a legal TPU block.
     # Padded KV positions sit above every real query position, so the
     # causal mask hides them; padded query rows are sliced off below.
-    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))) if pad_q else q
-    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else k
-    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else v
+    qt = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kt = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vt = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     n_q = (s + pad_q) // bq
     n_k = (s + pad_k) // bk
 
     def q_map(b_, h_, i, j):
-        return (b_, i, h_, 0)
+        return (b_, h_, i, 0)
 
     def kv_map(b_, h_, i, j):
         # Clamp out-of-range steps onto the visible span: the revisited
         # block index issues no new fetch, so skipped steps cost neither
         # DMA nor (predicated off) compute.
         j_lo, j_hi = _visible_j_range(i * bq, bq, bk, n_k, window)
-        return (b_, jnp.clip(j, j_lo, j_hi), h_ // g, 0)
+        return (b_, h_ // g, jnp.clip(j, j_lo, j_hi), 0)
 
     out = pl.pallas_call(
         functools.partial(
@@ -210,20 +213,21 @@ def _flash_forward(q, k, v, *, window, scale, block_q, block_k, interpret):
         ),
         grid=(b, h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), q_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
-            pl.BlockSpec((1, bk, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bq, hd), q_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
+            pl.BlockSpec((1, 1, bk, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), q_map),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd), q_map),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, _STATS_LANES), jnp.float32),   # running max
             pltpu.VMEM((bq, _STATS_LANES), jnp.float32),   # running denom
             pltpu.VMEM((bq, hd), jnp.float32),             # out accumulator
         ],
         interpret=interpret,
-    )(qp, kp, vp)
-    return out[:, :s] if pad_q else out
+        name="flash_attention",
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)[:, :s]
 
 
 # ---------------------------------------------------------------------------
@@ -423,62 +427,200 @@ def flash_attention(
 
 
 # ---------------------------------------------------------------------------
-# Decode kernel: q_len == 1 over a per-slot length-masked KV cache
+# Decode kernels: q_len == 1 over a per-slot length-masked KV cache, dense
+# (``flash_decode_attention``) or paged (``paged_flash_decode_attention``).
+#
+# Both share one kernel body.  A grid step holds ONE KV block of ALL heads:
+# the cache is viewed as ``(rows, tokens, KV * hd)`` (a free reshape of the
+# ``(.., KV, hd)`` layout), so the block's last two dims are
+# ``(block tokens, KV * hd)`` — the full lane extent, legal for Mosaic for
+# any head count.  The kernel walks the heads with static lane slices; per
+# head it runs the same fp32 online softmax as the forward kernel.  Per-
+# slot lengths (and block tables) ride as scalar-prefetch operands.
+#
+# Quantized pools (nf4 / int8 codes + per-block fp32 scales) dequantize
+# each head's tile in VMEM.  NF4 packs element ``i`` in the high nibble
+# and element ``i + hd/2`` in the low nibble of byte ``i``; rather than
+# re-joining the nibble planes (a lane shuffle), the kernel contracts each
+# plane with its half of the query — ``q.k = q[:hd/2].k_hi + q[hd/2:].k_lo``
+# — and accumulates the two output halves separately.
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, bk: int, n_k: int, scale: float,
-                   window: Optional[int]):
-    j = pl.program_id(2)
+def _plane_scales(scales: jnp.ndarray, width: int, offset: int,
+                  block: int) -> jnp.ndarray:
+    """``(rows, nsb)`` per-block scales -> ``(rows, width)`` per lane for
+    the plane whose lane ``i`` is head_dim element ``offset + i`` (block
+    ``(offset + i) // block``) — broadcasts and selects only, no
+    in-kernel repeat or reshape."""
+    rows, nsb = scales.shape
+    if nsb == 1:
+        return jnp.broadcast_to(scales, (rows, width))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    blk = (lane + offset) // block
+    out = jnp.zeros((rows, width), jnp.float32)
+    for b_ in range(nsb):
+        out = jnp.where(blk == b_, scales[:, b_:b_ + 1], out)
+    return out
+
+
+def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
+                   kv: int, hd: int, scale: float, window: Optional[int],
+                   fmt: Optional[str], quant_block: int, value_dtype):
+    """One KV block of every head for one slot.  Operand order:
+    ``prefetch..., q parts..., K refs..., V refs..., out parts...,
+    m, l, acc parts...`` — a "part" is the whole head_dim (fp, int8) or
+    one nibble plane's half of it (nf4)."""
+    n_parts = 2 if fmt == "nf4" else 1
+    n_kv_refs = 1 if fmt is None else 2          # codes (+ scales)
+    len_ref = refs[n_prefetch - 1]
+    pos = n_prefetch
+    q_refs = refs[pos:pos + n_parts]
+    pos += n_parts
+    k_refs = refs[pos:pos + n_kv_refs]
+    pos += n_kv_refs
+    v_refs = refs[pos:pos + n_kv_refs]
+    pos += n_kv_refs
+    o_refs = refs[pos:pos + n_parts]
+    pos += n_parts
+    m_ref, l_ref = refs[pos], refs[pos + 1]
+    acc_refs = refs[pos + 2:pos + 2 + n_parts]
+
+    b = pl.program_id(0)
+    j = pl.program_id(1)                                   # logical block
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
         l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for a in acc_refs:
+            a[...] = jnp.zeros_like(a)
 
-    length = len_ref[0, 0]                                 # this slot's len
+    length = len_ref[b]                                    # this slot's len
     q_pos = length - 1
-    # Per-slot skipping: the length is a runtime value, so out-of-range
-    # blocks are predicated off (the dense-cache index maps stay static;
-    # grid-level skipping needs the paged-cache follow-up).
+    # Blocks past the slot's length (and, windowed, before the window)
+    # predicate off all compute.  Paged: their index maps revisit the
+    # slot's last allocated pool row, so they cost no DMA either.
     should = j * bk < length
     if window is not None:
         should &= (j + 1) * bk > q_pos - window + 1
 
+    def head_parts(refs_, h):
+        """The ``n_parts`` (bk, part width) value tiles of head ``h``."""
+        if fmt is None:
+            return [refs_[0][0, :, h * hd:(h + 1) * hd]]
+        codes_ref, scale_ref = refs_
+        width = hd // n_parts
+        nsb = scale_ref.shape[-1] // kv
+        codes = codes_ref[0, :, h * width:(h + 1) * width]
+        scales = scale_ref[0, :, h * nsb:(h + 1) * nsb]
+        return [
+            (p * _plane_scales(scales, width, i * width, quant_block)
+             ).astype(value_dtype)
+            for i, p in enumerate(code_planes(codes, fmt))
+        ]
+
     @pl.when(should)
     def _step():
-        q = q_ref[0, 0]                                    # (G, hd)
-        k = k_ref[0, :, 0, :]                              # (bk, hd)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                          # (G, bk)
-        kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kv_pos < length
-        if window is not None:
-            mask &= (q_pos - kv_pos) < window
-        s = jnp.where(mask, s, MASK_VALUE)
+        kv_pos = None
+        for h in range(kv):
+            ks = head_parts(k_refs, h)
+            s = None
+            for q_ref, k in zip(q_refs, ks):
+                part = jax.lax.dot_general(
+                    q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )                                          # (G, bk)
+                s = part if s is None else s + part
+            s = s * scale
+            if kv_pos is None:
+                kv_pos = j * bk + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1
+                )
+                mask = kv_pos < length
+                if window is not None:
+                    mask &= (q_pos - kv_pos) < window
+            s = jnp.where(mask, s, MASK_VALUE)
 
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            m_prev = m_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_ref[h][:, :1] + jnp.sum(p, axis=-1,
+                                                       keepdims=True)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            for acc_ref, v in zip(acc_refs, head_parts(v_refs, h)):
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
 
     @pl.when(j == n_k - 1)
     def _finalize():
-        denom = l_ref[:, :1]
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.where(denom == 0.0, 1.0, denom)
-        ).astype(o_ref.dtype)
+        for h in range(kv):
+            denom = l_ref[h][:, :1]
+            denom = jnp.where(denom == 0.0, 1.0, denom)
+            for o_ref, acc_ref in zip(o_refs, acc_refs):
+                o_ref[0, h] = (acc_ref[h] / denom).astype(o_ref.dtype)
+
+
+def _decode_call(q, k_tiles, v_tiles, prefetch, *, block_map, bk, n_k,
+                 kv, hd, scale, window, fmt, quant_block, value_dtype,
+                 interpret, name):
+    """Shared ``pallas_call`` of the dense and paged decode kernels.
+
+    ``k_tiles`` / ``v_tiles``: the flattened ``(rows, tokens, lanes)``
+    operands (fp values, or codes + scales); ``block_map(b, j, *prefetch)``
+    gives the (row, token block) coordinates of slot ``b``'s ``j``-th KV
+    block."""
+    b, _, h, _ = q.shape
+    g = h // kv
+    qg = q.reshape(b, kv, g, hd)
+    if fmt == "nf4":
+        q_parts = [qg[..., :hd // 2], qg[..., hd // 2:]]
+    else:
+        q_parts = [qg]
+    n_pre = len(prefetch)
+
+    def q_map(b_, j, *pre):
+        return (b_, 0, 0, 0)
+
+    def kv_map(b_, j, *pre):
+        return (*block_map(b_, j, *pre), 0)
+
+    in_specs = [pl.BlockSpec((1,) + p.shape[1:], q_map) for p in q_parts]
+    for t in list(k_tiles) + list(v_tiles):
+        in_specs.append(pl.BlockSpec((1, bk, t.shape[2]), kv_map))
+    part_hd = [p.shape[-1] for p in q_parts]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pre,
+        grid=(b, n_k),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, kv, g, w), q_map) for w in part_hd],
+        scratch_shapes=[
+            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running max
+            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running denom
+        ] + [pltpu.VMEM((kv, g, w), jnp.float32) for w in part_hd],
+    )
+    outs = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, n_prefetch=n_pre, bk=bk, n_k=n_k,
+            kv=kv, hd=hd, scale=scale, window=window, fmt=fmt,
+            quant_block=quant_block, value_dtype=value_dtype,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, kv, g, w), q.dtype)
+                   for w in part_hd],
+        interpret=resolve_interpret(interpret),
+        name=name,
+    )(*prefetch, *q_parts, *k_tiles, *v_tiles)
+    out = jnp.concatenate(outs, axis=-1) if fmt == "nf4" else outs[0]
+    return out.reshape(b, 1, h, hd)
+
+
+def _lanes(x: jnp.ndarray) -> jnp.ndarray:
+    """``(rows, tokens, KV, w)`` -> ``(rows, tokens, KV * w)`` (free)."""
+    return x.reshape(x.shape[0], x.shape[1], -1)
 
 
 def flash_decode_attention(
@@ -510,7 +652,6 @@ def flash_decode_attention(
         raise ValueError(f"decode kernel expects q_len == 1, got {q_len}")
     s_max = k_cache.shape[1]
     kv = k_cache.shape[2]
-    g = h // kv
     bk = min(block_k, s_max)
     pad_k = (-s_max) % bk
     if pad_k:
@@ -518,36 +659,18 @@ def flash_decode_attention(
         k_cache = jnp.pad(k_cache, widths)
         v_cache = jnp.pad(v_cache, widths)
         s_max += pad_k
-    n_k = s_max // bk
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(hd)
-    qg = q.reshape(b, kv, g, hd)
-    lens = cache_len.reshape(b, 1).astype(jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, bk=bk, n_k=n_k, scale=scale, window=window
-        ),
-        grid=(b, kv, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, k_, j: (b_, 0)),
-            pl.BlockSpec((1, 1, g, hd), lambda b_, k_, j: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b_, k_, j: (b_, j, k_, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b_, k_, j: (b_, j, k_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, k_, j: (b_, k_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((g, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(lens, qg, k_cache, v_cache)
-    return out.reshape(b, 1, h, hd)
+    lens = cache_len.reshape(b).astype(jnp.int32)
+    return _decode_call(
+        q, [_lanes(k_cache)], [_lanes(v_cache)], [lens],
+        block_map=lambda b_, j, ln: (b_, j), bk=bk, n_k=s_max // bk,
+        kv=kv, hd=hd, scale=scale, window=window, fmt=None, quant_block=0,
+        value_dtype=q.dtype, interpret=interpret, name="flash_decode",
+    )
 
 
 # ---------------------------------------------------------------------------
-# Paged decode kernel: the KV cache is a block pool, per-slot block tables
+# Paged decode: the KV cache is a block pool, per-slot block tables
 # (repro.serve.paging) map logical KV blocks to pool rows.  The table is a
 # scalar-prefetch operand, so the grid index maps GATHER blocks through it
 # — the grid-level decode skipping the dense kernel could not do: a slot
@@ -556,135 +679,6 @@ def flash_decode_attention(
 # allocated pool row (tables are exported with that clamp) so they issue
 # no DMA, and their compute is predicated off by the length test.
 # ---------------------------------------------------------------------------
-
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, bs: int, n_b: int,
-                         scale: float, window: Optional[int]):
-    b = pl.program_id(0)
-    j = pl.program_id(2)                                   # logical block
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]                                    # this slot's len
-    q_pos = length - 1
-    # Unallocated blocks (j*bs >= length) predicate off all compute; their
-    # index maps revisited an already-resident pool row, so they cost
-    # neither DMA nor FLOPs — per-slot grid-level skipping.
-    should = j * bs < length
-    if window is not None:
-        should &= (j + 1) * bs > q_pos - window + 1
-
-    @pl.when(should)
-    def _step():
-        q = q_ref[0, 0]                                    # (G, hd)
-        k = k_ref[0, :, 0, :]                              # (bs, hd)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                          # (G, bs)
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kv_pos < length
-        if window is not None:
-            mask &= (q_pos - kv_pos) < window
-        s = jnp.where(mask, s, MASK_VALUE)
-
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_b - 1)
-    def _finalize():
-        denom = l_ref[:, :1]
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.where(denom == 0.0, 1.0, denom)
-        ).astype(o_ref.dtype)
-
-
-def _paged_decode_quant_kernel(bt_ref, len_ref, q_ref, kc_ref, ks_ref,
-                               vc_ref, vs_ref, *rest, bs: int, n_b: int,
-                               scale: float, window: Optional[int],
-                               fmt: str, quant_block: int, hd: int,
-                               value_dtype):
-    """Paged decode with dequant-in-VMEM: the gathered KV tiles are packed
-    codes + per-block scales; each visited block dequantizes through THE
-    shared ``core.quantize`` elementwise decode (``kv_dequant_values``)
-    before the online-softmax step — fp cache rows never exist in HBM.
-    NF4 carries its codebook as a ``(1, 16)`` operand (a kernel body
-    cannot capture host constants)."""
-    if fmt == "nf4":
-        cb_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        cb_ref = None
-    b = pl.program_id(0)
-    j = pl.program_id(2)                                   # logical block
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-    q_pos = length - 1
-    should = j * bs < length
-    if window is not None:
-        should &= (j + 1) * bs > q_pos - window + 1
-
-    @pl.when(should)
-    def _step():
-        q = q_ref[0, 0]                                    # (G, hd)
-        cb = cb_ref[...].reshape(-1) if cb_ref is not None else None
-        k = kv_dequant_values(
-            kc_ref[0, :, 0, :], ks_ref[0, :, 0, :],
-            fmt=fmt, block_size=quant_block, d=hd, codebook=cb,
-        ).astype(value_dtype)                              # (bs, hd)
-        v = kv_dequant_values(
-            vc_ref[0, :, 0, :], vs_ref[0, :, 0, :],
-            fmt=fmt, block_size=quant_block, d=hd, codebook=cb,
-        ).astype(value_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                          # (G, bs)
-        kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kv_pos < length
-        if window is not None:
-            mask &= (q_pos - kv_pos) < window
-        s = jnp.where(mask, s, MASK_VALUE)
-
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_b - 1)
-    def _finalize():
-        denom = l_ref[:, :1]
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.where(denom == 0.0, 1.0, denom)
-        ).astype(o_ref.dtype)
-
 
 def paged_flash_decode_attention(
     q: jnp.ndarray,               # (B, 1, H, hd)
@@ -723,81 +717,21 @@ def paged_flash_decode_attention(
     if q_len != 1:
         raise ValueError(f"decode kernel expects q_len == 1, got {q_len}")
     bs, kv = k_pool.shape[1], k_pool.shape[2]
-    g = h // kv
-    n_b = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(hd)
-    qg = q.reshape(b, kv, g, hd)
     lens = cache_len.reshape(b).astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
-
-    scratch_shapes = [
-        pltpu.VMEM((g, _STATS_LANES), jnp.float32),
-        pltpu.VMEM((g, _STATS_LANES), jnp.float32),
-        pltpu.VMEM((g, hd), jnp.float32),
-    ]
     if kv_quant is not None:
         if k_scales is None or v_scales is None:
             raise ValueError("kv_quant needs k_scales and v_scales")
-        hd_c = k_pool.shape[3]       # hd//2 packed (nf4) or hd (int8)
-        nsb = k_scales.shape[3]      # scale blocks per row
-        pool_spec = pl.BlockSpec(
-            (1, bs, 1, hd_c), lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)
-        )
-        scale_spec = pl.BlockSpec(
-            (1, bs, 1, nsb), lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)
-        )
-        in_specs = [
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
-            pool_spec, scale_spec, pool_spec, scale_spec,
-        ]
-        operands = [tables, lens, qg, k_pool, k_scales, v_pool, v_scales]
-        if kv_quant == "nf4":
-            in_specs.append(
-                pl.BlockSpec((1, 16), lambda b_, k_, j, bt, ln: (0, 0))
-            )
-            operands.append(jnp.asarray(NF4_CODEBOOK).reshape(1, 16))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,    # block tables, per-slot lengths
-            grid=(b, kv, n_b),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, g, hd),
-                                   lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
-            scratch_shapes=scratch_shapes,
-        )
-        out = pl.pallas_call(
-            functools.partial(
-                _paged_decode_quant_kernel, bs=bs, n_b=n_b, scale=scale,
-                window=window, fmt=kv_quant, quant_block=quant_block,
-                hd=hd, value_dtype=value_dtype or q.dtype,
-            ),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-            interpret=resolve_interpret(interpret),
-        )(*operands)
-        return out.reshape(b, 1, h, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,        # block tables, per-slot lengths
-        grid=(b, kv, n_b),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
-        scratch_shapes=scratch_shapes,
+        k_tiles = [_lanes(k_pool), _lanes(k_scales)]
+        v_tiles = [_lanes(v_pool), _lanes(v_scales)]
+    else:
+        k_tiles, v_tiles = [_lanes(k_pool)], [_lanes(v_pool)]
+    return _decode_call(
+        q, k_tiles, v_tiles, [tables, lens],
+        block_map=lambda b_, j, bt, ln: (bt[b_, j], 0), bk=bs,
+        n_k=tables.shape[1], kv=kv, hd=hd, scale=scale, window=window,
+        fmt=kv_quant, quant_block=quant_block,
+        value_dtype=value_dtype or q.dtype, interpret=interpret,
+        name="paged_decode" if kv_quant is None else f"paged_decode_{kv_quant}",
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, bs=bs, n_b=n_b, scale=scale, window=window
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        interpret=resolve_interpret(interpret),
-    )(tables, lens, qg, k_pool, v_pool)
-    return out.reshape(b, 1, h, hd)

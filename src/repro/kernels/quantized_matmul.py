@@ -1,49 +1,45 @@
 """Fused dequant-matmul Pallas kernel for blockwise-quantized weights.
 
 ``y = x @ dequant(Wq)`` with the dense weight never materialized in HBM:
-each grid step loads one activation row-block and one PACKED weight
-column tile (uint8 NF4 codes or int8) plus its per-block scales into
-VMEM, dequantizes the tile there (fp32), and runs the matmul with fp32
-accumulation on the MXU.  The HBM weight stream per decode tick drops
-from ``d_in * d_out * itemsize`` to the quantized bytes (~4x for NF4 of
-bf16) — exactly the dominant decode term ROADMAP §Perf B4/B5 left.
+the grid walks output column tiles (outer) and activation row blocks
+(inner).  At the first row block of a column tile the kernel dequantizes
+the PACKED tile (uint8 NF4 codes or int8, plus per-block scales) into a
+VMEM scratch in the activation dtype, one scale block of rows at a time;
+every row block of that column then runs one full-K matmul against the
+resident tile with fp32 accumulation on the MXU.  The HBM weight stream
+drops from ``d_in * d_out * itemsize`` to the quantized bytes (~4x for
+NF4 of bf16), and the dequantization runs once per tile, not once per
+row block.
 
-Numerics contract (the CI-gated bitwise equality): the kernel must equal
-``core.quantize.matmul_ref`` — dequantize-then-matmul in the same dtype —
-bit for bit.  This holds by construction:
+Numerics contract: the kernel equals ``core.quantize.matmul_ref`` —
+dequantize-then-matmul in the activation dtype.  Every weight element
+goes through the same fp32 operations (``code_planes`` then
+``scale_values``) in both paths, and the contraction axis is never
+split: each output element is ONE ``dot_general`` over the full ``d_in``
+with ``preferred_element_type=f32``.
 
-* the elementwise dequantization is literally the same function
-  (``core.quantize.dequant_values``), applied per column tile, and every
-  op in it is elementwise or a broadcast along the un-split ``d_in``
-  axis, so a tile of the reference's dequant equals the dequant of the
-  tile;
-* the grid tiles rows and output columns but never the contraction
-  axis — each output element is ONE ``dot_general`` over the full
-  ``d_in`` with ``preferred_element_type=f32`` in both paths, and tiled
-  full-K dots are bitwise equal to the monolithic dot (validated on
-  this backend for f32 and bf16, including non-divisible row counts).
-
-The jit'd wrapper pads rows and output columns to the block grid
-(zero-padded weight columns cannot perturb kept columns — each output
-column reads only its own weight column) and dispatches on the shared
-``kernels.vmem.vmem_footprint`` budget, falling back to the — bitwise
-identical — reference when a tile would not fit.
+The wrapper pads rows and output columns to the block grid (zero-padded
+weight columns cannot perturb kept columns) and picks the widest column
+tile whose grid step fits the VMEM budget.  It never falls back to the
+reference: a weight no tile fits raises.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import (
-    NF4_CODEBOOK,
     QuantizedLinear,
-    dequant_values,
+    code_planes,
     matmul_ref,
+    scale_values,
 )
 from repro.kernels.dispatch import resolve_interpret
 from repro.kernels.vmem import VMEM_BUDGET_BYTES, vmem_footprint
@@ -52,35 +48,56 @@ __all__ = [
     "quantized_matmul",
     "quantized_matmul_kernel_call",
     "quantized_matmul_ref",
-    "quantized_vmem_ok",
+    "quantized_tile_cols",
 ]
 
 # Re-exported so kernel-vs-reference callers (tests, the analysis
 # registry) name both paths from one module.
 quantized_matmul_ref = matmul_ref
 
+# Column tiles tried widest first (lane multiples of 128); a tile that
+# divides d_out is preferred, since padding the columns copies the weight.
+_COL_TILES = (512, 384, 256, 128)
 
-def _kernel(x_ref, q_ref, s_ref, *refs, fmt, block_size, d_in,
+
+def _kernel(x_ref, q_ref, s_ref, *refs, fmt, block_size, d_in, strip,
             has_row, has_col):
-    i = 0
-    cb = None
-    if fmt == "nf4":
-        cb = refs[i][...].reshape(-1)
-        i += 1
-    row = refs[i][...].reshape(-1) if has_row else None
-    i += has_row
-    col = refs[i][...].reshape(-1) if has_col else None
-    i += has_col
-    o_ref = refs[i]
-    w = dequant_values(
-        q_ref[...], s_ref[...], row, col,
-        fmt=fmt, block_size=block_size, d_in=d_in, codebook=cb,
-    ).astype(x_ref.dtype)
-    acc = jax.lax.dot_general(
-        x_ref[...], w, (((1,), (0,)), ((), ())),
+    row_ref = refs[0] if has_row else None
+    col_ref = refs[int(has_row)] if has_col else None
+    o_ref, w_ref = refs[int(has_row) + int(has_col):]
+    kp = q_ref.shape[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _dequant():
+        # The packed rows hold one plane (int8) or two nibble planes
+        # (nf4: natural rows [0, d_in/2) high, [d_in/2, d_in) low).  A
+        # strip never crosses a scale block or a plane, so it takes one
+        # scale row.
+        col = col_ref[...] if has_col else None
+
+        def body(k, carry):
+            r = pl.multiple_of(k * strip, strip)
+            planes = code_planes(q_ref[pl.ds(r, strip), :], fmt)
+            for p, vals in enumerate(planes):
+                row0 = p * kp + r                      # natural row
+                s = s_ref[pl.ds(row0 // block_size, 1), :]
+                rn = row_ref[pl.ds(row0, strip), :] if has_row else None
+                w = scale_values(vals, s, rn, col)
+                w_ref[pl.ds(row0, strip), :] = w.astype(w_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, kp // strip, body, 0)
+
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )
-    o_ref[...] = acc.astype(o_ref.dtype)
+    ).astype(o_ref.dtype)
+
+
+def _strip_rows(kp: int, block_size: int) -> int:
+    """Rows dequantized per loop step: a divisor of both the packed rows
+    and the scale block, so no strip straddles a plane or a block."""
+    return math.gcd(kp, block_size)
 
 
 def quantized_matmul_kernel_call(
@@ -109,67 +126,87 @@ def quantized_matmul_kernel_call(
     block_cols = min(block_cols, d_out)
     if rows % block_rows or d_out % block_cols:
         raise ValueError("rows/cols not divisible by block sizes")
-    grid = (rows // block_rows, d_out // block_cols)
+    grid = (d_out // block_cols, rows // block_rows)
 
     in_specs = [
-        pl.BlockSpec((block_rows, d_in), lambda i, j: (i, 0)),
-        pl.BlockSpec((kp, block_cols), lambda i, j: (0, j)),
-        pl.BlockSpec((nb, block_cols), lambda i, j: (0, j)),
+        pl.BlockSpec((block_rows, d_in), lambda j, i: (i, 0)),
+        pl.BlockSpec((kp, block_cols), lambda j, i: (0, j)),
+        pl.BlockSpec((nb, block_cols), lambda j, i: (0, j)),
     ]
     operands = [x, packed, scales]
-    if fmt == "nf4":
-        # the 64-byte codebook rides along as an operand: a kernel body
-        # cannot capture host constants
-        in_specs.append(pl.BlockSpec((1, 16), lambda i, j: (0, 0)))
-        operands.append(jnp.asarray(NF4_CODEBOOK).reshape(1, 16))
     if row_norm is not None:
-        in_specs.append(pl.BlockSpec((d_in, 1), lambda i, j: (0, 0)))
+        in_specs.append(pl.BlockSpec((d_in, 1), lambda j, i: (0, 0)))
         operands.append(row_norm)
     if col_norm is not None:
-        in_specs.append(pl.BlockSpec((1, block_cols), lambda i, j: (0, j)))
+        in_specs.append(pl.BlockSpec((1, block_cols), lambda j, i: (0, j)))
         operands.append(col_norm)
-    out_spec = pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j))
 
     kernel = functools.partial(
         _kernel, fmt=fmt, block_size=block_size, d_in=d_in,
+        strip=_strip_rows(kp, block_size),
         has_row=row_norm is not None, has_col=col_norm is not None,
     )
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=pl.BlockSpec((block_rows, block_cols), lambda j, i: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, d_out), x.dtype),
+        scratch_shapes=[pltpu.VMEM((d_in, block_cols), x.dtype)],
+        # the row axis reuses the column tile dequantized at its first
+        # step, so it must run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
         interpret=interpret,
+        name="quantized_matmul",
     )(*operands)
 
 
-def quantized_vmem_ok(qw: QuantizedLinear, block_rows: int,
-                      block_cols: int, dtype_bytes: int = 2) -> bool:
-    """Does one grid step's working set fit the VMEM budget?
-
-    Same arithmetic as the contract checker (``repro.analysis.kernels``)
-    via the shared ``kernels.vmem.vmem_footprint``: x tile + packed tile
-    + scale tile + the fp32 dequantized tile and its activation-dtype
-    cast + norm vectors + output tile.
-    """
-    d_in, d_out = qw.shape[-2], qw.shape[-1]
-    bc = min(block_cols, d_out)
+def _footprint(qw: QuantizedLinear, block_rows: int, bc: int,
+               dtype_bytes: int) -> int:
+    """VMEM bytes of one grid step: double-buffered x / packed / scale /
+    norm / output blocks, the dequantized-tile scratch, and one fp32
+    strip of dequant temporaries."""
+    d_in = qw.shape[-2]
     kp = qw.packed.shape[-2]
     nb = qw.scales.shape[-2]
-    blocks = [
+    strip = _strip_rows(kp, qw.block_size)
+    piped = [
         ((block_rows, d_in), dtype_bytes),       # x tile
         ((kp, bc), 1),                           # packed tile
         ((nb, bc), jnp.dtype(qw.scales.dtype).itemsize),
-        ((d_in, bc), 4),                         # fp32 dequantized tile
-        ((d_in, bc), dtype_bytes),               # activation-dtype cast
         ((block_rows, bc), dtype_bytes),         # output tile
     ]
     if qw.row_norm is not None:
-        blocks.append(((d_in, 1), 4))
+        piped.append(((d_in, 1), 4))
     if qw.col_norm is not None:
-        blocks.append(((1, bc), 4))
-    return vmem_footprint(blocks) < VMEM_BUDGET_BYTES
+        piped.append(((1, bc), 4))
+    return 2 * vmem_footprint(piped) + vmem_footprint([
+        ((d_in, bc), dtype_bytes),               # dequantized tile
+        ((strip, bc), 4 * 4),                    # fp32 strip temporaries
+    ])
+
+
+def quantized_tile_cols(qw: QuantizedLinear, block_rows: int,
+                        dtype_bytes: int = 2) -> int:
+    """Widest column tile whose grid step fits the VMEM budget (the
+    contract checker's arithmetic, ``kernels.vmem.vmem_footprint``).
+    Raises when even the narrowest tile does not fit."""
+    d_out = qw.shape[-1]
+    fits = [
+        bc for bc in sorted({min(t, d_out) for t in _COL_TILES}, reverse=True)
+        if _footprint(qw, block_rows, bc, dtype_bytes) <= VMEM_BUDGET_BYTES
+    ]
+    for bc in fits:
+        if d_out % bc == 0:
+            return bc
+    if fits:
+        return fits[0]
+    raise ValueError(
+        f"no column tile of a {qw.shape} {qw.fmt} weight fits the "
+        f"{VMEM_BUDGET_BYTES}-byte VMEM budget at {block_rows} rows"
+    )
 
 
 def quantized_matmul(
@@ -177,30 +214,31 @@ def quantized_matmul(
     qw: QuantizedLinear,
     *,
     block_rows: int = 128,
-    block_cols: int = 512,
+    block_cols: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused dequant-matmul ``x @ dequant(qw)`` for a 2-D quantized
-    weight; bitwise equal to :func:`quantized_matmul_ref` on every
-    shape (the VMEM fallback IS the reference, so dispatch never
-    changes results).  ``interpret=None`` resolves inside the kernel
-    call (interpret on CPU, Mosaic on TPU)."""
+    weight, equal to :func:`quantized_matmul_ref`.  ``block_rows`` caps
+    the row block (small batches use one block of their own padded
+    height); ``block_cols=None`` picks the widest tile that fits VMEM.
+    ``interpret=None`` resolves inside the kernel call (interpret on
+    CPU, Mosaic on TPU)."""
     if qw.ndim != 2:
         raise ValueError(f"quantized_matmul needs a 2-D weight, got "
                          f"{qw.shape}")
-    if not quantized_vmem_ok(
-        qw, block_rows, block_cols,
-        dtype_bytes=jnp.dtype(x.dtype).itemsize,
-    ):
-        return matmul_ref(x, qw)
     d_in, d_out = qw.shape
     batch = x.shape[:-1]
     xf = x.reshape(-1, d_in)
     rows = xf.shape[0]
+    # a decode batch of a few rows runs as one block of its own
+    # (sublane-padded) height rather than padding to ``block_rows``
+    block_rows = min(block_rows, -(-rows // 16) * 16)
+    dtype_bytes = jnp.dtype(x.dtype).itemsize
+    bc = (quantized_tile_cols(qw, block_rows, dtype_bytes)
+          if block_cols is None else min(block_cols, d_out))
     pad_r = (-rows) % block_rows
     if pad_r:
         xf = jnp.pad(xf, ((0, pad_r), (0, 0)))
-    bc = min(block_cols, d_out)
     pad_c = (-d_out) % bc
     packed, scales = qw.packed, qw.scales
     col = qw.col_norm

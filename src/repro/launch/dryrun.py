@@ -164,10 +164,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     # XLA's own cost_analysis() counts while (scan) bodies ONCE — useless
     # with scanned layers/microbatches; use the trip-count-aware parser and
     # keep the raw numbers for reference.
-    raw_cost = compiled.cost_analysis()
-    if isinstance(raw_cost, (list, tuple)):   # jax 0.4.x: [dict] per module
-        raw_cost = raw_cost[0] if raw_cost else {}
-    xla_cost = dict(raw_cost or {})
+    xla_cost = dict(compiled.cost_analysis() or {})
     cost = hlo_cost(hlo_text)
     coll = parse_collective_bytes(hlo_text)
     terms = roofline_terms(cfg, shape, n_chips, cost, coll)

@@ -14,40 +14,49 @@ import jax
 
 __all__ = [
     "make_abstract_mesh",
+    "make_mesh",
     "make_production_mesh",
     "make_host_mesh",
     "dp_axes",
 ]
 
 
+def _auto(n: int):
+    # Auto (GSPMD-propagated) axes: ``jax.make_mesh`` defaults to
+    # Explicit axes, under which ``with_sharding_constraint`` and the
+    # models' sharded reshapes raise ShardingTypeError.
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_abstract_mesh(
     shape: Sequence[int], axis_names: Sequence[str]
 ) -> "jax.sharding.AbstractMesh":
-    """Version-portable ``AbstractMesh`` constructor.
-
-    JAX <= 0.4.x takes ``AbstractMesh(((name, size), ...))`` while newer
-    releases take ``AbstractMesh(axis_sizes, axis_names)``.  Sharding-rule
-    validation (tests, dry-run planning) must not depend on which one the
-    environment ships.
-    """
+    """Device-free mesh for sharding-rule validation and planning."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+    return AbstractMesh(tuple(shape), tuple(axis_names),
+                        axis_types=_auto(len(shape)))
+
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
+              devices=None) -> jax.sharding.Mesh:
+    """Mesh over ``devices`` (default: all of them) with Auto axes —
+    every mesh in this repo is built here."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=_auto(len(shape)), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """TPU v5e: one pod = 16x16 = 256 chips; multi-pod = 2 pods = 512."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_host_mesh(data: int = 1, model: int = 1,
+                   devices=None) -> jax.sharding.Mesh:
+    """Small data x model mesh (tests, examples, the four-chip smoke)."""
+    return make_mesh((data, model), ("data", "model"), devices=devices)
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

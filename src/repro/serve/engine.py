@@ -411,7 +411,7 @@ class ServingEngine:
         # --- explicit shardings for every jitted entry point
         if mesh is not None:
             from repro.launch.shardings import (
-                cache_shardings, param_shardings, peft_shardings, replicated,
+                cache_shardings, param_shardings, peft_shardings,
             )
 
             struct = (
@@ -434,27 +434,23 @@ class ServingEngine:
                 jax.eval_shape(lambda: model.init_cache(n_slots, seq_bucket)),
                 spec=self.spec, paged=False,
             )
-            # chunked staging buffers are REPLICATED, not TP-sharded: the
-            # buffer holds one slot (negligible memory) and XLA's SPMD
-            # partitioner miscompiles the batch-1 chunk update when its
-            # head_dim is model-sharded on a mesh that also carries a
-            # data axis (wrong staged K/V values, jax 0.4.x CPU — the
-            # B=n_slots wave path partitions fine).  The landing
-            # ``insert_cache`` scatter re-shards into the partitioned
-            # serving cache.
-            self._chunk_sh = replicated(
-                mesh, jax.eval_shape(lambda: model.init_cache(1, seq_bucket))
+            # chunked staging buffers hold one slot: the batch axis cannot
+            # split over DP, so only the model rule applies
+            self._chunk_sh = cache_shardings(
+                self.cfg, mesh,
+                jax.eval_shape(lambda: model.init_cache(1, seq_bucket)),
+                spec=self.spec, paged=False,
             )
             self._repl = NamedSharding(mesh, P())
-            params = jax.device_put(
-                params, param_shardings(self.cfg, mesh, params, decode=True)
-            )
+            param_sh = param_shardings(self.cfg, mesh, params, decode=True)
+            params = jax.device_put(params, param_sh)
+            served_sh = None
             if served is not None:
-                served = jax.device_put(
-                    served, peft_shardings(mesh, served)
-                )
+                served_sh = peft_shardings(mesh, served)
+                served = jax.device_put(served, served_sh)
                 if adapters is not None:
                     self.bank = served
+            self._weights_sh = (param_sh, served_sh)
             if self.pool is not None:
                 # resident groups placed once (replicated, the adapter
                 # rule); the bank's sharding tree feeds every serving
@@ -467,8 +463,13 @@ class ServingEngine:
             self._cache_sh = self._wave_sh = self._chunk_sh = None
             self._repl = None
             self._bank_sh = None
+            self._weights_sh = None
         self.params = params
         self.peft = served if adapters is None else None
+        # the frozen base and the adapters enter every serving jit as its
+        # first (traced) argument: a closed-over array would be baked
+        # into the program as a literal
+        self._weights = (params, served)
         self.cache = (
             self.pager.init_cache(shardings=self._cache_sh)
             if self.pager is not None
@@ -561,51 +562,31 @@ class ServingEngine:
         repl = self._repl
         banked = self.bank is not None
         pooled = self.pool is not None
-        bank_sh = self._bank_sh
-        # every serving jit gains one trailing traced (B,) adapter_ids
-        # argument when a bank is attached — per-request selection stays
-        # inside the single fused program (O(1) dispatch either way).
-        # Pool mode appends the RESIDENT BANK itself as a further traced
-        # argument: hot-swapped rows must reach already-compiled programs,
-        # and a closed-over bank would bake the rows in as constants.
-        if self._paged:
-            if pooled:
-                fn = lambda cache, toks, bt, aids, bank: model.decode_step(  # noqa: E731, E501
-                    params, bank, cache, {"tokens": toks},
-                    block_tables=bt, mesh=decode_mesh, adapter_ids=aids,
-                )
-                in_sh = (cache_sh, repl, repl, repl, bank_sh)
-            elif banked:
-                fn = lambda cache, toks, bt, aids: model.decode_step(  # noqa: E731
-                    params, served, cache, {"tokens": toks},
-                    block_tables=bt, mesh=decode_mesh, adapter_ids=aids,
-                )
-                in_sh = (cache_sh, repl, repl, repl)
-            else:
-                fn = lambda cache, toks, bt: model.decode_step(  # noqa: E731
-                    params, served, cache, {"tokens": toks},
-                    block_tables=bt, mesh=decode_mesh,
-                )
-                in_sh = (cache_sh, repl, repl)
-        else:
-            if pooled:
-                fn = lambda cache, toks, aids, bank: model.decode_step(  # noqa: E731
-                    params, bank, cache, {"tokens": toks},
-                    adapter_ids=aids,
-                )
-                in_sh = (cache_sh, repl, repl, bank_sh)
-            elif banked:
-                fn = lambda cache, toks, aids: model.decode_step(  # noqa: E731
-                    params, served, cache, {"tokens": toks},
-                    adapter_ids=aids,
-                )
-                in_sh = (cache_sh, repl, repl)
-            else:
-                fn = lambda cache, toks: model.decode_step(  # noqa: E731
-                    params, served, cache, {"tokens": toks}
-                )
-                in_sh = (cache_sh, repl)
-        self._decode = _jit(fn, in_sh=in_sh, out_sh=(repl, cache_sh))
+        w_sh = self._weights_sh
+
+        def opt(flag, sh):
+            """Sharding of an optional trailing argument (None if absent)."""
+            return sh if flag else None
+
+        # Every serving jit takes the weights first, then its per-call
+        # arguments, then the optional trailing ones — block tables
+        # (paged), the traced (B,) adapter_ids (bank attached) and the
+        # resident bank itself (pool mode: hot-swapped rows must reach
+        # already-compiled programs).  Absent ones are passed as None.
+        # Per-request selection stays inside one fused program (O(1)
+        # dispatch either way).
+        def decode_fn(w, cache, toks, bt, aids, bank):
+            return model.decode_step(
+                w[0], bank if pooled else w[1], cache, {"tokens": toks},
+                block_tables=bt, mesh=decode_mesh, adapter_ids=aids,
+            )
+
+        self._decode = _jit(
+            decode_fn,
+            in_sh=(w_sh, cache_sh, repl, opt(self._paged, repl),
+                   opt(banked, repl), opt(pooled, self._bank_sh)),
+            out_sh=(repl, cache_sh),
+        )
         # greedy sampler over the fused decode's (B, 1, V) logits,
         # device-side: returns (B, 1) int32 next tokens WITHOUT a host
         # round-trip, so the async front end can chain them straight
@@ -620,65 +601,38 @@ class ServingEngine:
         )
         if admission != "prefill":
             self._prefill = None
-        elif pooled:
-            self._prefill = _jit(
-                lambda toks, lens, aids, bank: model.prefill(
-                    params, bank, {"tokens": toks}, lengths=lens,
-                    adapter_ids=aids,
-                ),
-                in_sh=(repl, repl, repl, bank_sh),
-                out_sh=(repl, wave_sh),
-            )
-        elif banked:
-            self._prefill = _jit(
-                lambda toks, lens, aids: model.prefill(
-                    params, served, {"tokens": toks}, lengths=lens,
-                    adapter_ids=aids,
-                ),
-                in_sh=(repl, repl, repl),
-                out_sh=(repl, wave_sh),
-            )
         else:
+            def prefill_fn(w, toks, lens, aids, bank):
+                return model.prefill(
+                    w[0], bank if pooled else w[1], {"tokens": toks},
+                    lengths=lens, adapter_ids=aids,
+                )
+
             self._prefill = _jit(
-                lambda toks, lens: model.prefill(
-                    params, served, {"tokens": toks}, lengths=lens
-                ),
-                in_sh=(repl, repl),
+                prefill_fn,
+                in_sh=(w_sh, repl, repl, opt(banked, repl),
+                       opt(pooled, self._bank_sh)),
                 out_sh=(repl, wave_sh),
             )
         if not self._can_chunk:
             self._chunk_fn = None
-        elif pooled:
-            self._chunk_fn = _jit(
-                lambda staged, toks, pos, n_valid, aids, bank:
-                model.prefill_chunk(
-                    params, bank, {"tokens": toks}, staged, pos, n_valid,
-                    adapter_ids=aids,
-                ),
-                in_sh=(chunk_sh, repl, repl, repl, repl, bank_sh),
-                out_sh=(repl, chunk_sh),
-            )
-        elif banked:
-            self._chunk_fn = _jit(
-                lambda staged, toks, pos, n_valid, aids: model.prefill_chunk(
-                    params, served, {"tokens": toks}, staged, pos, n_valid,
-                    adapter_ids=aids,
-                ),
-                in_sh=(chunk_sh, repl, repl, repl, repl),
-                out_sh=(repl, chunk_sh),
-            )
         else:
+            def chunk_fn(w, staged, toks, pos, n_valid, aids, bank):
+                return model.prefill_chunk(
+                    w[0], bank if pooled else w[1], {"tokens": toks}, staged,
+                    pos, n_valid, adapter_ids=aids,
+                )
+
             self._chunk_fn = _jit(
-                lambda staged, toks, pos, n_valid: model.prefill_chunk(
-                    params, served, {"tokens": toks}, staged, pos, n_valid
-                ),
-                in_sh=(chunk_sh, repl, repl, repl),
+                chunk_fn,
+                in_sh=(w_sh, chunk_sh, repl, repl, repl, opt(banked, repl),
+                       opt(pooled, self._bank_sh)),
                 out_sh=(repl, chunk_sh),
             )
-        # the insert scatter runs eagerly on one device (current behavior)
-        # but becomes a jitted call with explicit shardings under a mesh —
-        # the wave lands in the partitioned cache without a host gather.
-        # `None` entries leave the wave/staging input as committed (wave
+        # the insert scatter is one jitted program (eagerly, quantizing a
+        # full-width KV wave holds several wave-sized temporaries); under
+        # a mesh it carries explicit shardings, so the wave lands in the
+        # partitioned cache without a host gather.  `None` entries leave the wave/staging input as committed (wave
         # buffers arrive already sharded from the prefill/chunk jits; the
         # two layouts differ in batch extent, so one spec can't cover
         # both).  Compile count is bounded: wave sizes <= n_slots, token
@@ -699,14 +653,9 @@ class ServingEngine:
             def _insert(cache, ids, wave, bt):
                 return model.insert_cache(cache, ids, wave, block_tables=bt)
 
-        if mesh is None:
-            self._insert_fn = _insert
-        else:
-            self._insert_fn = jax.jit(
-                _insert,
-                in_shardings=(cache_sh, repl, None, None),
-                out_shardings=cache_sh,
-            )
+        self._insert_fn = _jit(
+            _insert, in_sh=(cache_sh, repl, None, None), out_sh=cache_sh
+        )
 
         # Correctness tooling: every jitted entry point carries its
         # documented compilation bound (eager fns are skipped inside
@@ -743,8 +692,8 @@ class ServingEngine:
           request — chunk-aligned then bucketed, so one compile per
           distinct staging extent, which may exceed ``max_len`` by up
           to ``prefill_chunk + seq_bucket``.
-        * ``insert`` — ``n_slots * (n_buckets + 2)``: the scatter (jitted
-          only under a mesh) sees one layout per distinct
+        * ``insert`` — ``n_slots * (n_buckets + 2)``: the scatter sees
+          one layout per distinct
           ``(wave rows, token bucket)`` pair; chunked staging adds
           single-row layouts whose token extent may exceed ``max_len``
           by up to ``prefill_chunk + seq_bucket``.
@@ -823,16 +772,23 @@ class ServingEngine:
         return self.bank.id_of(req.adapter) if self.bank is not None else 0
 
     def _decode_args(self, toks) -> List[Any]:
-        """Positional args of the fused decode jit for this engine shape
-        (cache, tokens [, block_tables] [, adapter_ids] [, bank])."""
-        args: List[Any] = [self.cache, toks]
-        if self._paged:
-            args.append(self.pager.device_tables())
-        if self.bank is not None:
-            args.append(jnp.asarray(self._adapter_ids))
-        if self.pool is not None:
-            args.append(self.pool.device_bank())
-        return args
+        """Positional args of the fused decode jit: weights, cache,
+        tokens, block tables, adapter ids, resident bank (None where the
+        engine has none)."""
+        return [
+            self._weights, self.cache, toks,
+            self.pager.device_tables() if self._paged else None,
+            jnp.asarray(self._adapter_ids) if self.bank is not None else None,
+            self._bank_arg(),
+        ]
+
+    def _ids_arg(self, ids):
+        """Adapter ids as a traced argument (None without a bank)."""
+        return jnp.asarray(ids, jnp.int32) if self.bank is not None else None
+
+    def _bank_arg(self):
+        """The resident bank argument (pool mode), else None."""
+        return self.pool.device_bank() if self.pool is not None else None
 
     def _acquire_adapter(self, req: Request) -> bool:
         """Pool mode: pin the request's tenant (loading it — possibly
@@ -1010,19 +966,10 @@ class ServingEngine:
             lens[row] = len(p)
         for row, req in enumerate(wave):
             wave_ids[row] = self._req_adapter_id(req)
-        if self.pool is not None:
-            logits, wave_cache = self._prefill(
-                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(wave_ids),
-                self.pool.device_bank(),
-            )
-        elif self.bank is not None:
-            logits, wave_cache = self._prefill(
-                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(wave_ids)
-            )
-        else:
-            logits, wave_cache = self._prefill(
-                jnp.asarray(toks), jnp.asarray(lens)
-            )
+        logits, wave_cache = self._prefill(
+            self._weights, jnp.asarray(toks), jnp.asarray(lens),
+            self._ids_arg(wave_ids), self._bank_arg(),
+        )
         self.stats["prefill_calls"] += 1
         slot_ids = np.asarray(free[: len(wave)], np.int32)
         self._insert_wave(slot_ids, wave_cache, lengths)
@@ -1098,21 +1045,10 @@ class ServingEngine:
         n_valid = min(c, len(tokens) - pos)
         toks = np.zeros((1, c), np.int32)
         toks[0, :n_valid] = tokens[pos : pos + n_valid]
-        if self.pool is not None:
-            logits, st["staged"] = self._chunk_fn(
-                st["staged"], jnp.asarray(toks), pos, n_valid,
-                jnp.asarray([st["aid"]], jnp.int32),
-                self.pool.device_bank(),
-            )
-        elif self.bank is not None:
-            logits, st["staged"] = self._chunk_fn(
-                st["staged"], jnp.asarray(toks), pos, n_valid,
-                jnp.asarray([st["aid"]], jnp.int32),
-            )
-        else:
-            logits, st["staged"] = self._chunk_fn(
-                st["staged"], jnp.asarray(toks), pos, n_valid
-            )
+        logits, st["staged"] = self._chunk_fn(
+            self._weights, st["staged"], jnp.asarray(toks), pos, n_valid,
+            self._ids_arg([st["aid"]]), self._bank_arg(),
+        )
         self.stats["chunk_calls"] += 1
         st["pos"] = pos + n_valid
         if st["pos"] < len(tokens):
@@ -1298,3 +1234,50 @@ class ServingEngine:
         ) and ticks < max_ticks:
             self.step()
             ticks += 1
+
+    def teacher_forced_logits(self, prompt: Sequence[int],
+                              continuation: Sequence[int],
+                              adapter: Optional[str] = None) -> np.ndarray:
+        """Logits of the serving path with the next tokens forced.
+
+        Admits ``prompt`` (as ``adapter``'s tenant) through this engine's
+        own prefill, cache and adapter residency, then feeds
+        ``continuation`` one token per fused decode step instead of the
+        sampled ones.  Returns ``(len(continuation), vocab)`` fp32 logits
+        — row ``i`` is the prediction after ``continuation[i]`` — so two
+        engines (kernel vs reference backends, sharded vs one device) can
+        be compared logit for logit on the same token stream.  Needs an
+        idle engine; the slot is freed again on return.
+        """
+        if self.queue or self._chunking is not None or any(
+                r is not None for r in self.slots):
+            raise RuntimeError("teacher_forced_logits needs an idle engine")
+        req = Request(uid=-1, prompt=list(prompt),
+                      max_new_tokens=len(continuation) + 1)
+        self.validate(req, adapter)
+        self._admit(queue=deque([req]), chunk=False)
+        while self._chunking is not None:
+            self._step_chunked()
+        if req not in self.slots:
+            raise RuntimeError("the prompt could not be admitted")
+        slot = self.slots.index(req)
+        active = np.arange(self.n_slots) == slot
+        rows = []
+        for tok in continuation:
+            self._last_token[slot] = tok
+            if self._paged:
+                self._ensure_growth(active)
+            logits = self.dispatch_decode(
+                jnp.asarray(self._last_token.reshape(-1, 1)), active
+            )
+            rows.append(np.asarray(
+                logits[slot, 0, : self.cfg.vocab_size], np.float32
+            ))
+            self._lengths[slot] += 1
+        self.slots[slot] = None
+        self._adapter_ids[slot] = 0
+        self._release_adapter(req)
+        if self._paged:
+            self.pager.release(slot)
+        self._update_gauges()
+        return np.stack(rows)

@@ -207,13 +207,14 @@ MINI = textwrap.dedent("""\
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
     from repro.configs import get_smoke, get_peft
+    from repro.launch.mesh import make_mesh
     from repro.models.common import ShapeConfig
     from repro.launch.shardings import batch_shardings, state_shardings, \\
         cache_shardings
     from repro.launch.steps import build_programs
     from repro.models import cache_specs
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke("qwen2-0.5b").replace(
         d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, vocab_size=256,
     )
@@ -248,6 +249,7 @@ MINI = textwrap.dedent("""\
 
 def test_mini_dryrun_8_devices_subprocess():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual devices; never the chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(
         [sys.executable, "-c", MINI], env=env, capture_output=True,

@@ -182,6 +182,28 @@ def test_paged_engine_matches_dense(arch):
     assert outs["paged"] == outs["dense"]
 
 
+@pytest.mark.parametrize("mode", ["dense", "paged"])
+def test_teacher_forced_logits_follow_greedy_decode(mode):
+    """Forcing an engine's own greedy tokens reproduces them: row ``i``'s
+    argmax is the token greedy decoding emitted after continuation
+    ``i``, and the engine is idle again afterwards (a repeat call
+    admits and returns the same logits)."""
+    cfg = get_smoke("qwen2-0.5b")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    engine = ServingEngine(model, params, n_slots=2, max_len=64,
+                           cache=mode, block_size=8)
+    prompt = [5, 9, 13, 40, 2]
+    req = Request(uid=0, prompt=list(prompt), max_new_tokens=6)
+    engine.submit(req)
+    engine.run()
+    logits = engine.teacher_forced_logits(prompt, req.output[:-1])
+    assert logits.shape == (5, cfg.vocab_size)
+    assert np.argmax(logits, axis=-1).tolist() == req.output[1:]
+    again = engine.teacher_forced_logits(prompt, req.output[:-1])
+    np.testing.assert_array_equal(again, logits)
+
+
 def test_paged_engine_pallas_backend_matches_dense_reference():
     cfg = get_smoke("qwen2-0.5b")
     model = build_model(cfg)

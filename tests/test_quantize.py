@@ -268,16 +268,28 @@ def test_base_matmul_dispatch():
 
 
 def test_vmem_gate_falls_back_to_reference():
-    """Oversized column blocks trip the VMEM gate; the fallback IS the
-    reference, so dispatch never changes results."""
-    from repro.kernels.quantized_matmul import quantized_vmem_ok
+    """The kernel never hands a weight back to the reference: the wrapper
+    narrows the column tile until one grid step fits the VMEM budget
+    (same result as the reference), and raises where no tile fits."""
+    from repro.kernels.quantized_matmul import quantized_tile_cols
 
     w = _rand(50, (4096, 4096), scale=0.3)
     qw = quantize_linear(w, "nf4", block_size=64)
-    assert not quantized_vmem_ok(qw, block_rows=1024, block_cols=4096)
-    x = _rand(51, (2, 4096)).astype(jnp.bfloat16)
-    out = quantized_matmul(x, qw, block_rows=1024, block_cols=4096)
+    bc = quantized_tile_cols(qw, block_rows=512)
+    assert 128 <= bc < 512
+    x = _rand(51, (600, 4096)).astype(jnp.bfloat16)
+    out = quantized_matmul(x, qw, block_rows=512, interpret=True)
     assert _bitwise_equal(out, matmul_ref(x, qw))
+
+    huge = jax.eval_shape(
+        lambda w: quantize_linear(w, "nf4", block_size=64),
+        jax.ShapeDtypeStruct((65536, 128), jnp.bfloat16),
+    )
+    with pytest.raises(ValueError, match="VMEM budget"):
+        jax.eval_shape(
+            lambda x, q: quantized_matmul(x, q, interpret=True),
+            jax.ShapeDtypeStruct((2, 65536), jnp.bfloat16), huge,
+        )
 
 
 # --------------------------------------------------------------------------

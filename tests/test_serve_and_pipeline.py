@@ -134,9 +134,10 @@ _PIPE = textwrap.dedent("""\
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.train.pipeline import pipeline_apply, bubble_fraction
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     L, M, MB, D = 8, 6, 2, 16
     key = jax.random.PRNGKey(0)
     w = jax.random.normal(key, (L, D, D)) / np.sqrt(D)
@@ -181,6 +182,7 @@ _PIPE = textwrap.dedent("""\
 
 def test_pipeline_parallel_subprocess_4_stages():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual devices; never the chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(
         [sys.executable, "-c", _PIPE], env=env, capture_output=True,

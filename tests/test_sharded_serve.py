@@ -578,11 +578,12 @@ def test_cache_shardings_griffin_ring_pool_leaves():
     mesh = make_abstract_mesh((2, 4), ("data", "model"))
     sh = cache_shardings(cfg, mesh, view.struct(), spec=view.spec,
                          paged=True)
-    assert sh["pos"].spec == P(None, ("data",), None)
-    assert sh["k"].spec[1] == ("data",) and sh["k"].spec[2] is None
+    # PartitionSpec normalizes a one-axis tuple ("data",) to "data"
+    assert sh["pos"].spec == P(None, "data", None)
+    assert sh["k"].spec[1] == "data" and sh["k"].spec[2] is None
     # dense leaves: slot axis over data
-    assert sh["lru1"].spec[1] == ("data",)
-    assert sh["tail_lru1"].spec[0] == ("data",)
+    assert sh["lru1"].spec[1] == "data"
+    assert sh["tail_lru1"].spec[0] == "data"
 
     # paged=False (dense engine) must keep the original stripe rules for
     # the SAME spec tree — paging is strictly additive
@@ -638,6 +639,7 @@ def test_multidevice_suite_subprocess_fallback():
         pytest.skip("subprocess fallback disabled "
                     "(REPRO_MULTIDEVICE_SUBPROCESS=0)")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # virtual devices; never the chip
     env["REPRO_FORCE_MULTIDEVICE"] = "1"
     env["REPRO_MULTIDEVICE_SUBPROCESS"] = "0"     # no recursion
     env["PYTHONPATH"] = os.pathsep.join(

@@ -1,0 +1,231 @@
+"""Compile the main-path Pallas kernels for a TPU v5e at minicpm-2b widths.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+block shapes off the (8, 128) tiling, in-kernel gathers and reshapes,
+sub-32-bit matmul accumulators, or more VMEM than a kernel may use.  These
+tests hand the kernels ``ShapeDtypeStruct`` operands placed on a described
+(not attached) ``v5e:2x2`` topology and run the real TPU compiler, so a
+kernel that would be refused on the chip fails here first.
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process at a time may load the TPU library, and every
+pytest-xdist worker imports this module.  Nothing here runs on a device.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.minicpm_2b import FULL as MINICPM
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+D = MINICPM.d_model                     # 2304
+H = MINICPM.n_heads                     # 36 (MHA)
+HD = MINICPM.head_dim                   # 64
+FF = MINICPM.d_ff                       # 5760
+QB = MINICPM.quant_block_size           # 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # repro: allow(broad-except) any failure to describe the chip means these tests cannot run here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args) -> str:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+# ---------------------------------------------------------------- attention
+
+def test_flash_forward(one_chip):
+    from repro.kernels.flash_attention import flash_attention
+
+    q = _spec((4, 1024, H, HD), jnp.bfloat16, one_chip)
+    _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), q, q, q
+    )
+
+
+def test_flash_vjp_train_step(one_chip):
+    from repro.kernels.flash_attention import flash_attention
+
+    q = _spec((4, 1024, H, HD), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_flash_decode_dense(one_chip):
+    from repro.kernels.flash_attention import flash_decode_attention
+
+    q = _spec((8, 1, H, HD), jnp.bfloat16, one_chip)
+    kc = _spec((8, 1024, H, HD), jnp.bfloat16, one_chip)
+    lens = _spec((8,), jnp.int32, one_chip)
+    _compiled_text(
+        lambda q, k, v, n: flash_decode_attention(q, k, v, n,
+                                                  interpret=False),
+        q, kc, kc, lens,
+    )
+
+
+@pytest.mark.parametrize("kv_quant", [None, "nf4", "int8"])
+def test_paged_decode(one_chip, kv_quant):
+    from repro.core.quantize import quantize_kv
+    from repro.kernels.flash_attention import paged_flash_decode_attention
+
+    b, bs, n_pool, n_b = 8, 16, 512, 40
+    q = _spec((b, 1, H, HD), jnp.bfloat16, one_chip)
+    bt = _spec((b, n_b), jnp.int32, one_chip)
+    lens = _spec((b,), jnp.int32, one_chip)
+    if kv_quant is None:
+        pool = _spec((n_pool, bs, H, HD), jnp.bfloat16, one_chip)
+        _compiled_text(
+            lambda q, k, v, t, n: paged_flash_decode_attention(
+                q, k, v, t, n, interpret=False),
+            q, pool, pool, bt, lens,
+        )
+        return
+    codes, scales = jax.eval_shape(
+        lambda x: quantize_kv(x, kv_quant, block_size=QB),
+        jax.ShapeDtypeStruct((n_pool, bs, H, HD), jnp.bfloat16),
+    )
+    cp = _spec(codes.shape, codes.dtype, one_chip)
+    sp = _spec(scales.shape, scales.dtype, one_chip)
+    _compiled_text(
+        lambda q, kc, ks, vc, vs, t, n: paged_flash_decode_attention(
+            q, kc, vc, t, n, interpret=False, kv_quant=kv_quant,
+            k_scales=ks, v_scales=vs, quant_block=QB,
+            value_dtype=jnp.bfloat16),
+        q, cp, sp, cp, sp, bt, lens,
+    )
+
+
+# ----------------------------------------------------------- quantized base
+
+@pytest.mark.parametrize("fmt", ["nf4", "int8"])
+@pytest.mark.parametrize("d_in,d_out", [(D, D), (D, FF), (FF, D)])
+@pytest.mark.parametrize("rows", [8, 4096])
+def test_quantized_matmul(one_chip, fmt, d_in, d_out, rows):
+    from repro.core.quantize import quantize_linear
+    from repro.kernels.quantized_matmul import quantized_matmul
+
+    qw = jax.eval_shape(
+        lambda w: quantize_linear(w, fmt, block_size=QB),
+        jax.ShapeDtypeStruct((d_in, d_out), jnp.bfloat16),
+    )
+    qw = jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), qw
+    )
+    x = _spec((rows, d_in), jnp.bfloat16, one_chip)
+    _compiled_text(
+        lambda x, qw: quantized_matmul(x, qw, interpret=False), x, qw
+    )
+
+
+# ---------------------------------------------------------- adapter banks
+
+@pytest.mark.parametrize("seq", [1, 512])
+def test_banked_gather_lora(one_chip, seq):
+    from repro.kernels.banked_gather import (
+        banked_lora_delta,
+        banked_lora_linear,
+    )
+
+    b, rank, rows = 8, 8, 5
+    x = _spec((b, seq, D), jnp.bfloat16, one_chip)
+    w = _spec((D, D), jnp.bfloat16, one_chip)
+    a = _spec((rows, D, rank), jnp.float32, one_chip)
+    bb = _spec((rows, rank, D), jnp.float32, one_chip)
+    ids = _spec((b,), jnp.int32, one_chip)
+    _compiled_text(
+        lambda x, a, bb, ids: banked_lora_delta(
+            x, a, bb, ids, scale=2.0, interpret=False),
+        x, a, bb, ids,
+    )
+    _compiled_text(
+        lambda x, w, a, bb, ids: banked_lora_linear(
+            x, w, a, bb, ids, scale=2.0, interpret=False),
+        x, w, a, bb, ids,
+    )
+
+
+# ------------------------------------------------------ fused QuanTA chain
+
+def _quanta_adapter():
+    from repro.core.quanta import QuantaAdapter
+
+    return jax.eval_shape(lambda: QuantaAdapter.create(
+        jax.random.PRNGKey(0), D, D, dims_in=(16, 12, 12),
+        dtype=jnp.bfloat16,
+    ))
+
+
+# The fused QuanTA kernels are off the served and trained paths (pool-served
+# QuanTA runs jnp.take + vmap; training refuses the pallas backend).  Mosaic
+# refuses their in-kernel reshape of the activation block into the tensor
+# axes; a repair of the kernels flips these cases.
+_CHAIN_RESHAPE = pytest.mark.xfail(
+    strict=True,
+    reason="Mosaic: infer-vector-layout: unsupported shape cast "
+           "(256x2304 -> 4096x144) in the in-kernel reshape",
+)
+
+
+@_CHAIN_RESHAPE
+def test_quanta_apply(one_chip):
+    from repro.kernels.ops import quanta_apply_fused
+
+    ad = jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), _quanta_adapter()
+    )
+    x = _spec((512, D), jnp.bfloat16, one_chip)
+    _compiled_text(
+        lambda x, ad: quanta_apply_fused(x, ad, interpret=False), x, ad
+    )
+
+
+@_CHAIN_RESHAPE
+def test_quanta_linear(one_chip):
+    from repro.kernels.ops import quanta_linear_fused
+
+    ad = jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), _quanta_adapter()
+    )
+    x = _spec((512, D), jnp.bfloat16, one_chip)
+    w = _spec((D, D), jnp.bfloat16, one_chip)
+    _compiled_text(
+        lambda x, w, ad: quanta_linear_fused(x, w, ad, interpret=False),
+        x, w, ad,
+    )
