@@ -50,12 +50,13 @@ compiles):
 * **hot-swap adapter churn** — a 64-tenant ``AdapterStore`` registry
   served through an 8-row ``AdapterPool`` resident bank: two waves
   round-robined over 16 distinct tenants force load/evict churn
-  mid-run.  The row reports steady tokens/sec, the donated row-scatter
-  swap latency (p50), the ``adapter_bytes_resident`` (capacity-fixed
-  device bank) vs ``adapter_bytes_registry`` (host factors, grows with
-  tenants) split, and the load/eviction counts; two churned tenants are
-  asserted token-for-token against dedicated cold engines and the
-  compile guard asserts the serving jits never recompiled across swaps.
+  mid-run.  The row reports steady tokens/sec, the mean tenant load
+  time (``adapter_load_s`` over loads), the ``adapter_bytes_resident``
+  (capacity-fixed device bank) vs ``adapter_bytes_registry`` (host
+  factors, grows with tenants) split, and the load/eviction counts;
+  two churned tenants are asserted token-for-token against dedicated
+  cold engines and the compile guard asserts the serving jits never
+  recompiled across swaps.
 
 * **open-loop front end** (``--open-loop``) — a seeded Poisson arrival
   schedule (two SLA classes, ``interactive``/``batch``) streamed
@@ -492,12 +493,13 @@ def bench_adapter_churn(family: str, arch: str, model, params):
                 "from its cold single-tenant engine"
             )
 
+    load_ms = 1e3 * stats["adapter_load_s"] / stats["adapter_loads"]
     return [csv_row(
         f"serve_churn_{family}_pool8", 1e6 / tps,
         f"toks/s={tps:.0f} tenants={stats['adapter_tenants']} "
         f"loads={stats['adapter_loads']} "
         f"evictions={stats['adapter_evictions']} "
-        f"swap_p50={stats['adapter_swap_p50'] * 1e6:.0f}us "
+        f"load_ms={load_ms:.1f} "
         f"resident_bytes={stats['adapter_bytes_resident']} "
         f"registry_bytes={stats['adapter_bytes_registry']}",
     )]

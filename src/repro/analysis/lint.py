@@ -87,6 +87,7 @@ HOT_PATHS = {
     "ServeFrontend.serve",
     "ServeFrontend._dispatch",
     "ServeFrontend._land_inflight",
+    "ServeFrontend._land",
     "ServeFrontend._chain_safe",
     "ServeFrontend._ensure_chain",
     "ServeFrontend._flush_streams",

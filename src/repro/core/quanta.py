@@ -413,19 +413,22 @@ class QuantaAdapter(Adapter):
 
     def delta(self, x: jnp.ndarray) -> jnp.ndarray:
         """``T_theta x`` (folded) or ``T_theta x - S x`` (fold-free) for
-        batched ``x (..., d_in) -> (..., d_out)``."""
-        h = x.astype(self.tensors[0].dtype)
-        y = apply_sequential(
-            h, self.tensors, self.dims_in, self.pairs, self.dims_out
-        )
-        if self.frozen is not None:
-            # stop_gradient on S only — the S chain is linear in x, so
-            # gradients still flow through x to upstream layers
-            y = y - apply_sequential(
-                h, jax.lax.stop_gradient(self.frozen),
-                self.dims_in, self.pairs, self.dims_out,
+        batched ``x (..., d_in) -> (..., d_out)``.  Runs under the
+        ``quanta`` name scope, by which a profile attributes the chain's
+        device time."""
+        with jax.named_scope("quanta"):
+            h = x.astype(self.tensors[0].dtype)
+            y = apply_sequential(
+                h, self.tensors, self.dims_in, self.pairs, self.dims_out
             )
-        return y.astype(x.dtype)
+            if self.frozen is not None:
+                # stop_gradient on S only — the S chain is linear in x,
+                # so gradients still flow through x to upstream layers
+                y = y - apply_sequential(
+                    h, jax.lax.stop_gradient(self.frozen),
+                    self.dims_in, self.pairs, self.dims_out,
+                )
+            return y.astype(x.dtype)
 
     def matrix(self) -> jnp.ndarray:
         """Full ``(d_in, d_out)`` update matrix (fold-free subtracts S)."""
@@ -459,17 +462,19 @@ class QuantaAdapter(Adapter):
                 # bases) + each chain of the T - S pair through the
                 # fused-chain kernel
                 s_view = self.unfrozen(jax.lax.stop_gradient(self.frozen))
-                return base_matmul(x, w, backend) + (
-                    quanta_apply_fused(x, self.unfrozen())
-                    - quanta_apply_fused(x, s_view)
-                ).astype(x.dtype)
+                y = base_matmul(x, w, backend)
+                with jax.named_scope("quanta"):
+                    d = (quanta_apply_fused(x, self.unfrozen())
+                         - quanta_apply_fused(x, s_view)).astype(x.dtype)
+                return y + d
             if isinstance(w, QuantizedLinear):
                 # quantized frozen base: fused dequant-matmul for the
                 # base + the fused chain kernel for the delta (the dense
                 # weight is never materialized in HBM)
-                return base_matmul(x, w, backend) + quanta_apply_fused(
-                    x, self
-                ).astype(x.dtype)
+                y = base_matmul(x, w, backend)
+                with jax.named_scope("quanta"):
+                    d = quanta_apply_fused(x, self).astype(x.dtype)
+                return y + d
             from repro.kernels.ops import quanta_linear_fused
 
             return quanta_linear_fused(x, w, self)

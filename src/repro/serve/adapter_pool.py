@@ -74,7 +74,6 @@ from repro.core.bank import (
 )
 from repro.core.peft import _set_path, flatten_paths
 from repro.serve.paging import addressable_nbytes
-from repro.serve.scheduler import LatencyHistogram
 
 __all__ = ["AdapterPool", "AdapterStore", "RowAllocator"]
 
@@ -139,6 +138,7 @@ class AdapterStore:
         self.max_tenants = max_tenants
         self._names: List[str] = []
         self._members: Dict[str, Dict[str, Tuple[Any, Any]]] = {}
+        self._nbytes = 0
 
     # ------------------------------------------------------------ registry
     def register(self, name: str, entry: TenantEntry) -> int:
@@ -151,8 +151,14 @@ class AdapterStore:
                 "(sized at construction — it bounds the resident bank's "
                 "id_map extent)"
             )
-        self._members[name] = tenant_path_adapters(name, entry)
+        members = tenant_path_adapters(name, entry)
+        self._members[name] = members
         self._names.append(name)
+        self._nbytes += sum(
+            addressable_nbytes(leaf)
+            for adapter, _ in members.values()
+            for leaf in jax.tree_util.tree_leaves(adapter)
+        )
         return len(self._names)
 
     def get(self, name: str) -> Dict[str, Tuple[Any, Any]]:
@@ -187,13 +193,9 @@ class AdapterStore:
 
     @property
     def nbytes(self) -> int:
-        """Registry bytes: every registered tenant's factor leaves."""
-        return int(sum(
-            addressable_nbytes(leaf)
-            for members in self._members.values()
-            for adapter, _ in members.values()
-            for leaf in jax.tree_util.tree_leaves(adapter)
-        ))
+        """Registry bytes: every registered tenant's factor leaves
+        (summed as each registers; registered factors never change)."""
+        return int(self._nbytes)
 
 
 class AdapterPool:
@@ -230,23 +232,28 @@ class AdapterPool:
         # one donated in-place row scatter, traced once per structure
         # profile (row indices are traced scalars, so churn within a
         # profile never retraces).  CPU ignores donation with a warning,
-        # so only donate where the backend honors it.  The lambda gives
-        # THIS pool its own jit identity: jax's tracing cache is keyed by
-        # the underlying callable, so jitting the module-level function
-        # directly would pool compile counts across AdapterPool instances
-        # and break per-engine compile_guard accounting.
+        # so only donate where the backend honors it.  The nested def
+        # gives THIS pool its own jit identity (jax's tracing cache is
+        # keyed by the underlying callable, so jitting the module-level
+        # function directly would pool compile counts across AdapterPool
+        # instances and break per-engine compile_guard accounting) and
+        # the program a stable name, ``jit_swap_fn``.
         donate = () if jax.default_backend() == "cpu" else (0,)
+
+        def swap_fn(groups, tenants, rows, stacked):
+            return _scatter_rows(groups, tenants, rows, stacked)
+
         self.swap_fn = jax.jit(
-            lambda groups, tenants, rows, stacked: _scatter_rows(
-                groups, tenants, rows, stacked),
-            donate_argnums=donate, static_argnums=3,
+            swap_fn, donate_argnums=donate, static_argnums=3,
         )
-        # lifecycle gauges (merged into ServingEngine.stats each tick)
+        # lifecycle gauges (merged into ServingEngine.stats each tick):
+        # ``load_s`` sums the wall seconds of every ``_load``
         self.loads = 0
+        self.load_s = 0.0
         self.evictions = 0
         self.acquire_denied = 0
         self.evict_denied = 0
-        self.swap_hist = LatencyHistogram()
+        self._resident_nbytes = self._tree_nbytes()
 
     # ------------------------------------------------------------- building
     @staticmethod
@@ -376,6 +383,7 @@ class AdapterPool:
         self.tree.clear()
         self.tree.update(new_tree)
         self._placed_mesh = mesh
+        self._resident_nbytes = self._tree_nbytes()
 
     # ------------------------------------------------------------ lifecycle
     def _path_node(self, path: str) -> _BankPath:
@@ -408,43 +416,46 @@ class AdapterPool:
         """Scatter the tenant's factors into freshly allocated rows —
         one donated jitted update — and point its id_map entries at
         them.  Callers ensured every needed group has a free row."""
-        t0 = time.perf_counter()
-        gid = self.store.id_of(name)
-        rows: Dict[Tuple[str, int], int] = {}
-        for path, gi, _ in profile:
-            rows[(path, gi)] = self._alloc[(path, gi)].alloc()
+        with jax.profiler.TraceAnnotation("pool.load", tenant=name):
+            t0 = time.perf_counter()
+            gid = self.store.id_of(name)
+            rows: Dict[Tuple[str, int], int] = {}
+            for path, gi, _ in profile:
+                rows[(path, gi)] = self._alloc[(path, gi)].alloc()
 
-        groups_in = tuple(
-            self._path_node(path).groups[gi] for path, gi, _ in profile
-        )
-        tenants = tuple(adapter for _, _, adapter in profile)
-        row_ixs = tuple(rows[(path, gi)] for path, gi, _ in profile)
-        stacked = tuple(self._stacked[path] for path, gi, _ in profile)
-        new_groups = self.swap_fn(groups_in, tenants, row_ixs, stacked)
-        jax.block_until_ready(new_groups)     # honest swap-latency gauge
+            groups_in = tuple(
+                self._path_node(path).groups[gi] for path, gi, _ in profile
+            )
+            tenants = tuple(adapter for _, _, adapter in profile)
+            row_ixs = tuple(rows[(path, gi)] for path, gi, _ in profile)
+            stacked = tuple(self._stacked[path] for path, gi, _ in profile)
+            new_groups = self.swap_fn(groups_in, tenants, row_ixs, stacked)
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(new_groups)    # honest load time
 
-        for (path, gi, _), new_g in zip(profile, new_groups):
-            node = self._path_node(path)
-            gs = list(node.groups)
-            gs[gi] = new_g
-            _set_path(self.tree, path, _BankPath(
-                groups=tuple(gs), id_maps=node.id_maps,
-                stacked=node.stacked, delta_forms=node.delta_forms,
-            ))
-            node.id_maps[gi][gid] = rows[(path, gi)]
-        self._resident[name] = {"rows": rows, "pins": 0, "stamp": 0}
-        self._touch(name)
-        self.loads += 1
-        self.swap_hist.record(max(time.perf_counter() - t0, 0.0))
+            for (path, gi, _), new_g in zip(profile, new_groups):
+                node = self._path_node(path)
+                gs = list(node.groups)
+                gs[gi] = new_g
+                _set_path(self.tree, path, _BankPath(
+                    groups=tuple(gs), id_maps=node.id_maps,
+                    stacked=node.stacked, delta_forms=node.delta_forms,
+                ))
+                node.id_maps[gi][gid] = rows[(path, gi)]
+            self._resident[name] = {"rows": rows, "pins": 0, "stamp": 0}
+            self._touch(name)
+            self.loads += 1
+            self.load_s += time.perf_counter() - t0
 
     def _evict(self, name: str) -> None:
-        ent = self._resident.pop(name)
-        gid = self.store.id_of(name)
-        for (path, gi), row in ent["rows"].items():
-            node = self._path_node(path)
-            node.id_maps[gi][gid] = 0        # unreachable before freed
-            self._alloc[(path, gi)].free(row)
-        self.evictions += 1
+        with jax.profiler.TraceAnnotation("pool.evict", tenant=name):
+            ent = self._resident.pop(name)
+            gid = self.store.id_of(name)
+            for (path, gi), row in ent["rows"].items():
+                node = self._path_node(path)
+                node.id_maps[gi][gid] = 0        # unreachable before freed
+                self._alloc[(path, gi)].free(row)
+            self.evictions += 1
 
     def _ensure_resident(self, name: str) -> bool:
         if name in self._resident:
@@ -517,7 +528,11 @@ class AdapterPool:
     # -------------------------------------------------------------- gauges
     def resident_nbytes(self) -> int:
         """Device bytes of the resident bank (groups + id_maps) — fixed
-        by capacity, NOT by tenant count."""
+        by capacity, NOT by tenant count (summed at build and again at
+        placement, which is all that changes it)."""
+        return self._resident_nbytes
+
+    def _tree_nbytes(self) -> int:
         return int(sum(
             addressable_nbytes(leaf)
             for leaf in jax.tree_util.tree_leaves(self.tree)
@@ -533,8 +548,7 @@ class AdapterPool:
             "adapter_evictions": self.evictions,
             "adapter_acquire_denied": self.acquire_denied,
             "adapter_evict_denied": self.evict_denied,
-            "adapter_swap_p50": self.swap_hist.percentile(50),
-            "adapter_swap_p99": self.swap_hist.percentile(99),
+            "adapter_load_s": self.load_s,
         }
 
 

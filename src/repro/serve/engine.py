@@ -195,6 +195,20 @@ dense and paged, mixed adapter tenants).  ``Request.arrival_time`` /
 gauges (``stats["ttft_p50"]`` / ``["queue_depth"]`` / tick-latency
 percentiles) surfaced in ``benchmarks/serve_bench.py --open-loop``.
 
+Tracing: each layer boundary of a tick is a ``jax.profiler``
+``TraceAnnotation`` (one inactive check when no profiler runs).  The
+front end's ``serve.tick`` holds ``serve.chunk`` (uid, pos),
+``serve.admit`` > ``serve.prefill`` (rows, bucket) > ``serve.insert``
+(rows), ``serve.grow`` > ``serve.preempt`` (uid), ``serve.dispatch``
+(chained), ``serve.land``, ``serve.stream``, ``serve.gauges`` and the
+adapter pool's ``pool.load`` / ``pool.evict`` (tenant).  Every wait on
+a device result is a ``serve.wait`` span.  The programs keep stable names
+(``jit_decode_fn``, ``jit_prefill_fn``, ``jit_chunk_fn``,
+``jit_insert_fn``, ``jit_sample_fn``, the pool's ``jit_swap_fn``, the
+front end's ``jit_merge_tokens_fn``), and the adapter math inside them
+runs under the ``adapter`` and ``quanta`` name scopes (``core.bank``,
+``core.quanta``).
+
 Correctness tooling (``repro.analysis``):
 
 * every jitted entry point is registered on a
@@ -221,6 +235,7 @@ Correctness tooling (``repro.analysis``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from collections import deque
@@ -496,6 +511,7 @@ class ServingEngine:
         self.stats: Dict[str, Any] = {
             "decode_calls": 0, "prefill_calls": 0, "chunk_calls": 0,
             "preemptions": 0,
+            "queue_depth": {},
             "adapter_bytes": resident_b,
             "adapter_bytes_resident": resident_b,
             "adapter_bytes_registry": registry_b,
@@ -593,12 +609,11 @@ class ServingEngine:
         # into the next decode dispatch (double-buffered ticks) and the
         # closed loop fetches them with one D2H copy.
         vocab = self.cfg.vocab_size
-        self._sample = _jit(
-            lambda logits: jnp.argmax(
-                logits[:, :, :vocab], -1
-            ).astype(jnp.int32),
-            in_sh=repl, out_sh=repl,
-        )
+
+        def sample_fn(logits):
+            return jnp.argmax(logits[:, :, :vocab], -1).astype(jnp.int32)
+
+        self._sample = _jit(sample_fn, in_sh=repl, out_sh=repl)
         if admission != "prefill":
             self._prefill = None
         else:
@@ -645,16 +660,16 @@ class ServingEngine:
             # stripe into codes + scales at commit).
             serve_spec = self.serve_spec
 
-            def _insert(cache, ids, wave, bt):
+            def insert_fn(cache, ids, wave, bt):
                 return insert_cache_slots(
                     serve_spec, cache, ids, wave, block_tables=bt
                 )
         else:
-            def _insert(cache, ids, wave, bt):
+            def insert_fn(cache, ids, wave, bt):
                 return model.insert_cache(cache, ids, wave, block_tables=bt)
 
         self._insert_fn = _jit(
-            _insert, in_sh=(cache_sh, repl, None, None), out_sh=cache_sh
+            insert_fn, in_sh=(cache_sh, repl, None, None), out_sh=cache_sh
         )
 
         # Correctness tooling: every jitted entry point carries its
@@ -849,14 +864,22 @@ class ServingEngine:
         return merged
 
     def queue_depths(self) -> Dict[str, int]:
-        """Queued (not yet admitted) requests per latency class.  Covers
-        the engine's own FIFO; the SLA front end overwrites the gauge
-        from its class queues each tick."""
+        """Queued (not yet admitted) requests per latency class in the
+        engine's own FIFO: ``step`` writes it to ``stats["queue_depth"]``
+        each tick (the SLA front end writes its class queues' depths
+        there instead)."""
         depths: Dict[str, int] = {}
         for req in self.queue:
             depths[req.latency_class] = depths.get(req.latency_class, 0) + 1
         return depths
 
+    def _fetch(self, x) -> np.ndarray:
+        """Copy a device result to the host, the one place the serving
+        loop waits on the device for tokens: a ``serve.wait`` span."""
+        with jax.profiler.TraceAnnotation("serve.wait"):
+            return np.asarray(x)
+
+    @functools.partial(jax.profiler.annotate_function, name="serve.gauges")
     def _update_gauges(self) -> None:
         ttft = self.ttft_all()
         self.stats.update(
@@ -864,7 +887,6 @@ class ServingEngine:
             ttft_p99=ttft.percentile(99),
             tick_p50=self.tick_hist.percentile(50),
             tick_p99=self.tick_hist.percentile(99),
-            queue_depth=self.queue_depths(),
         )
         if self.pool is not None:
             pstats = self.pool.stats()
@@ -892,6 +914,7 @@ class ServingEngine:
         return min(-(-n // self.seq_bucket) * self.seq_bucket, self.max_len)
 
     # ------------------------------------------------------------ admission
+    @functools.partial(jax.profiler.annotate_function, name="serve.admit")
     def _admit(self, queue=None, chunk: bool = True) -> None:
         """One admission pass.  ``queue`` substitutes any deque-protocol
         source (truthiness / ``[0]`` peek / ``popleft``) for the engine's
@@ -966,16 +989,18 @@ class ServingEngine:
             lens[row] = len(p)
         for row, req in enumerate(wave):
             wave_ids[row] = self._req_adapter_id(req)
-        logits, wave_cache = self._prefill(
-            self._weights, jnp.asarray(toks), jnp.asarray(lens),
-            self._ids_arg(wave_ids), self._bank_arg(),
-        )
-        self.stats["prefill_calls"] += 1
-        slot_ids = np.asarray(free[: len(wave)], np.int32)
-        self._insert_wave(slot_ids, wave_cache, lengths)
-        first = np.asarray(
-            jnp.argmax(logits[:, 0, : self.cfg.vocab_size], -1), np.int32  # repro: allow(host-jnp) greedy sampling: one argmax+D2H per tick is the sampler, not a leak
-        )
+        with jax.profiler.TraceAnnotation("serve.prefill", rows=len(wave),
+                                          bucket=s):
+            logits, wave_cache = self._prefill(
+                self._weights, jnp.asarray(toks), jnp.asarray(lens),
+                self._ids_arg(wave_ids), self._bank_arg(),
+            )
+            self.stats["prefill_calls"] += 1
+            slot_ids = np.asarray(free[: len(wave)], np.int32)
+            self._insert_wave(slot_ids, wave_cache, lengths)
+            first = self._fetch(
+                jnp.argmax(logits[:, 0, : self.cfg.vocab_size], -1)  # repro: allow(host-jnp) greedy sampling: one argmax+D2H per tick is the sampler, not a leak
+            ).astype(np.int32)
         for row, (slot, req) in enumerate(zip(free, wave)):
             self.slots[slot] = req
             self._lengths[slot] = lengths[row]
@@ -991,18 +1016,16 @@ class ServingEngine:
         """Land a prefill wave (or a finished chunked staging buffer) in
         the serving cache — dense slot scatter, or block-table scatter
         after allocating each row's blocks."""
-        if self._paged:
-            for slot, n in zip(slot_ids, lengths):
-                self.pager.ensure(int(slot), int(n))
-            ext = self.pager.wave_page_extent(wave_cache)
-            nb = -(-ext // self.pager.block_size)
-            tables = self.pager.wave_tables(slot_ids, nb)
+        tables = None
+        with jax.profiler.TraceAnnotation("serve.insert", rows=len(slot_ids)):
+            if self._paged:
+                for slot, n in zip(slot_ids, lengths):
+                    self.pager.ensure(int(slot), int(n))
+                ext = self.pager.wave_page_extent(wave_cache)
+                nb = -(-ext // self.pager.block_size)
+                tables = self.pager.wave_tables(slot_ids, nb)
             self.cache = self._insert_fn(
                 self.cache, slot_ids, wave_cache, tables
-            )
-        else:
-            self.cache = self._insert_fn(
-                self.cache, slot_ids, wave_cache, None
             )
 
     # --------------------------------------------------- chunked admission
@@ -1042,35 +1065,38 @@ class ServingEngine:
         req, c = st["req"], self.prefill_chunk
         tokens = st["tokens"]
         pos = st["pos"]
-        n_valid = min(c, len(tokens) - pos)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :n_valid] = tokens[pos : pos + n_valid]
-        logits, st["staged"] = self._chunk_fn(
-            self._weights, st["staged"], jnp.asarray(toks), pos, n_valid,
-            self._ids_arg([st["aid"]]), self._bank_arg(),
-        )
-        self.stats["chunk_calls"] += 1
-        st["pos"] = pos + n_valid
-        if st["pos"] < len(tokens):
-            return
-        # final chunk: first token + the SAME insert_cache scatter as a wave
-        slot = st["slot"]
-        self._insert_wave(
-            np.asarray([slot], np.int32), st["staged"],
-            np.asarray([len(tokens)], np.int32),
-        )
-        tok = int(jnp.argmax(  # repro: allow(host-jnp) greedy sampling: one argmax+D2H per chunk is the sampler, not a leak
-            logits[0, 0, : self.cfg.vocab_size]
-        ))
-        self.slots[slot] = req
-        self._lengths[slot] = len(tokens)
-        self._adapter_ids[slot] = st["aid"]
-        self._last_token[slot] = tok
-        self._fresh[slot] = True
-        req.output.append(tok)
-        self._note_first_token(req)
-        self._chunking = None
-        self._update_gauges()
+        with jax.profiler.TraceAnnotation("serve.chunk", uid=req.uid,
+                                          pos=pos):
+            n_valid = min(c, len(tokens) - pos)
+            toks = np.zeros((1, c), np.int32)
+            toks[0, :n_valid] = tokens[pos : pos + n_valid]
+            logits, st["staged"] = self._chunk_fn(
+                self._weights, st["staged"], jnp.asarray(toks), pos, n_valid,
+                self._ids_arg([st["aid"]]), self._bank_arg(),
+            )
+            self.stats["chunk_calls"] += 1
+            st["pos"] = pos + n_valid
+            if st["pos"] < len(tokens):
+                return
+            # final chunk: first token + the SAME insert_cache scatter as
+            # a wave
+            slot = st["slot"]
+            self._insert_wave(
+                np.asarray([slot], np.int32), st["staged"],
+                np.asarray([len(tokens)], np.int32),
+            )
+            tok = int(self._fetch(jnp.argmax(  # repro: allow(host-jnp) greedy sampling: one argmax+D2H per chunk is the sampler, not a leak
+                logits[0, 0, : self.cfg.vocab_size]
+            )))
+            self.slots[slot] = req
+            self._lengths[slot] = len(tokens)
+            self._adapter_ids[slot] = st["aid"]
+            self._last_token[slot] = tok
+            self._fresh[slot] = True
+            req.output.append(tok)
+            self._note_first_token(req)
+            self._chunking = None
+            self._update_gauges()
 
     def _admit_replay(self, free: Sequence[int], wave: List[Request]) -> None:
         """Fallback: prompts replay token-by-token through ``decode_step``
@@ -1119,16 +1145,19 @@ class ServingEngine:
         ``arrival_time`` / ``latency_class`` / the generated prefix all
         survive preemption."""
         req = self.slots[slot]
-        self.slots[slot] = None
-        self._adapter_ids[slot] = 0
-        self.pager.release(slot)
-        # unpin the tenant: its rows may be reclaimed while the request
-        # queues, and re-admission re-acquires (reloading if evicted)
-        self._release_adapter(req)
-        (self.requeue_hook or self.queue.appendleft)(req)
+        with jax.profiler.TraceAnnotation("serve.preempt", uid=req.uid):
+            self.slots[slot] = None
+            self._adapter_ids[slot] = 0
+            self.pager.release(slot)
+            # unpin the tenant: its rows may be reclaimed while the
+            # request queues, and re-admission re-acquires (reloading
+            # if evicted)
+            self._release_adapter(req)
+            (self.requeue_hook or self.queue.appendleft)(req)
         self.stats["preemptions"] = self.stats.get("preemptions", 0) + 1
 
     # ----------------------------------------------------------------- tick
+    @functools.partial(jax.profiler.annotate_function, name="serve.grow")
     def _ensure_growth(self, active: np.ndarray) -> None:
         """Paged alloc-on-append: the incoming token may cross a block
         boundary, so every active slot's arena must hold one more token
@@ -1212,6 +1241,7 @@ class ServingEngine:
     def step(self) -> None:
         t0 = self.clock()
         self._admit()
+        self.stats["queue_depth"] = self.queue_depths()
         active = np.array([r is not None for r in self.slots])
         if not active.any():
             return
@@ -1219,10 +1249,11 @@ class ServingEngine:
             self._ensure_growth(active)
             if not active.any():
                 return
-        toks = jnp.asarray(self._last_token.reshape(-1, 1))
-        logits = self.dispatch_decode(toks, active)
-        nxt = np.asarray(self._sample(logits))[:, 0]
-        self._postprocess(nxt, active)
+        with jax.profiler.TraceAnnotation("serve.dispatch", chained=0):
+            toks = jnp.asarray(self._last_token.reshape(-1, 1))
+            sampled = self._sample(self.dispatch_decode(toks, active))
+        with jax.profiler.TraceAnnotation("serve.land"):
+            self._postprocess(self._fetch(sampled)[:, 0], active)
         self.tick_hist.record(max(self.clock() - t0, 0.0))
         if sanitize.enabled():
             self.compile_guard.assert_ok()

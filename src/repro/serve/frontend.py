@@ -20,7 +20,8 @@ as they land:
   tick's tokens only afterwards.  Host work (admission, block
   allocation, streaming) and the device step run concurrently.
   Slots freshly admitted between the two dispatches take their host
-  token through the ``merge_toks`` jit (``where(fresh, host, chain)``).
+  token through the ``merge_tokens_fn`` jit (``where(fresh, host,
+  chain)``).
 * **Survival rule** (chain safety): a chained dispatch is only issued
   when no in-flight slot can complete in the un-landed tick — no
   ``eos_id``, token budget and ``max_len`` headroom >= 2, and (paged)
@@ -44,21 +45,23 @@ as they land:
   ``scheduler.InterleavePolicy``: chunk bursts sized by whether decode
   slots are active and by the admitting request's SLA priority.
 
-Every jitted entry point the front end adds (``merge_toks``; the
-engine's ``sample`` is registered by the engine itself) carries a
-documented compile bound on ``engine.compile_guard``, so
-``REPRO_SANITIZE=1`` holds the async loop to the same retrace/leak
-discipline as the closed loop — ``tick()`` asserts the bounds under
-sanitize exactly like ``ServingEngine.step``.
+Every jitted entry point the front end adds (``merge_tokens_fn``,
+registered as ``merge_toks``; the engine's ``sample`` is registered by
+the engine itself) carries a documented compile bound on
+``engine.compile_guard``, so ``REPRO_SANITIZE=1`` holds the async loop
+to the same retrace/leak discipline as the closed loop — ``tick()``
+asserts the bounds under sanitize exactly like ``ServingEngine.step``.
 """
 
 from __future__ import annotations
 
 import asyncio
+import functools
 import queue as _queue
 import time
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -151,9 +154,11 @@ class ServeFrontend:
 
     ``stats``: ``ticks`` (front-end scheduling ticks), ``chained``
     (double-buffered dispatches that skipped the host round-trip),
-    ``host_dispatch`` (synchronous fallbacks), plus the engine's own
-    gauges (``engine.stats`` — ``queue_depth`` is overwritten each tick
-    from the scheduler's class queues).
+    ``host_dispatch`` (synchronous fallbacks), plus the
+    engine's own gauges (``engine.stats`` — ``queue_depth`` is written
+    each tick from the scheduler's class queues).  Each tick is a
+    ``serve.tick`` profiler span holding the engine's and the pool's
+    spans (see ``repro.serve.engine``).
     """
 
     def __init__(
@@ -187,25 +192,21 @@ class ServeFrontend:
         self.stats: Dict[str, int] = {
             "ticks": 0, "chained": 0, "host_dispatch": 0,
         }
+
         # fresh-slot token merge for chained dispatch: where admission
         # wrote a newer host token than the device chain, take the host's.
+        # A nested def: each front end keeps its own jit (and compile
+        # count), under the stable program name ``jit_merge_tokens_fn``.
+        def merge_tokens_fn(fresh, host, chain):
+            return jnp.where(fresh[:, None], host, chain)
+
         mesh = engine.mesh
         if mesh is None:
-            import jax
-
-            self._merge_toks = jax.jit(
-                lambda fresh, host, chain: jnp.where(
-                    fresh[:, None], host, chain
-                )
-            )
+            self._merge_toks = jax.jit(merge_tokens_fn)
         else:
-            import jax
-
             repl = engine._repl
             self._merge_toks = jax.jit(
-                lambda fresh, host, chain: jnp.where(
-                    fresh[:, None], host, chain
-                ),
+                merge_tokens_fn,
                 in_shardings=(repl, repl, repl),
                 out_shardings=repl,
             )
@@ -264,6 +265,7 @@ class ServeFrontend:
                 return False
         return True
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.grow")
     def _ensure_chain(self, active: np.ndarray) -> bool:
         """Reserve paged blocks for BOTH pending tokens of a chained
         dispatch: an in-flight slot lands one token and immediately
@@ -288,20 +290,33 @@ class ServeFrontend:
         return True
 
     def _land_inflight(self) -> None:
-        """Fetch the un-landed tick's sampled tokens (the ONE D2H copy
-        per tick) and run the engine's postprocess under its
-        dispatch-time active mask."""
-        sampled, act = self._inflight
-        self._inflight = None
-        nxt = np.asarray(sampled)[:, 0]
-        self.engine._postprocess(nxt, act)
+        """Land the un-landed tick and clear the in-flight buffer."""
+        inflight, self._inflight = self._inflight, None
+        self._land(*inflight)
 
-    def _dispatch(self, toks, active: np.ndarray) -> None:
-        """Dispatch one decode tick and hold its sampled tokens as the
-        new in-flight buffer (device future — no host sync here)."""
+    def _land(self, sampled, act: np.ndarray) -> None:
+        """Fetch a tick's sampled tokens (the ONE D2H copy per tick) and
+        run the engine's postprocess under its dispatch-time active
+        mask."""
         eng = self.engine
-        logits = eng.dispatch_decode(toks, active)
-        self._inflight = (eng._sample(logits), active.copy())
+        with jax.profiler.TraceAnnotation("serve.land"):
+            eng._postprocess(eng._fetch(sampled)[:, 0], act)
+
+    def _dispatch(self, active: np.ndarray, chained: bool) -> None:
+        """Dispatch one decode tick from the host tokens (``chained``:
+        from the in-flight tick's sampled tokens, the host's where a
+        slot was admitted since) and hold its sampled tokens as the new
+        in-flight buffer (device future — no host sync here)."""
+        eng = self.engine
+        with jax.profiler.TraceAnnotation("serve.dispatch",
+                                          chained=int(chained)):
+            toks = jnp.asarray(eng._last_token.reshape(-1, 1))
+            if chained:
+                toks = self._merge_toks(
+                    jnp.asarray(eng._fresh), toks, self._inflight[0]
+                )
+            logits = eng.dispatch_decode(toks, active)
+            self._inflight = (eng._sample(logits), active.copy())
 
     # ------------------------------------------------------------- tick
     def tick(self) -> bool:
@@ -310,89 +325,84 @@ class ServeFrontend:
         Returns True when any work was done (False = idle: nothing
         ready before the next scheduled arrival)."""
         eng = self.engine
-        t0 = eng.clock()
-        did = False
+        live = sum(s is not None for s in eng.slots)
+        with jax.profiler.TraceAnnotation("serve.tick",
+                                          tick=self.stats["ticks"],
+                                          live=live):
+            t0 = eng.clock()
+            did = False
 
-        # 1) chunked-prefill burst per the interleave policy
-        if eng._chunking is not None:
-            decoding = (
-                self._inflight is not None
-                or any(s is not None for s in eng.slots)
-            )
-            cls = self.scheduler.classes.get(
-                eng._chunking["req"].latency_class
-            )
-            steps = self.interleave.chunk_steps(
-                decoding, cls.priority if cls is not None else None
-            )
-            for _ in range(steps):
-                if eng._chunking is None:
-                    break
-                eng._step_chunked()
-                did = True
-
-        # 2) land BEFORE admission when the un-landed tick may complete
-        # a request — its freed slots then admit this very tick.
-        if self._inflight is not None and not self._chain_safe():
-            self._land_inflight()
-            did = True
-
-        # 3) EDF admission from the scheduler's ready view
-        eng._admit(queue=self.scheduler.view(eng.clock()), chunk=False)
-        active = np.array([s is not None for s in eng.slots])
-
-        # 4) decode dispatch: chained (double-buffered) when safe
-        if self._inflight is not None:
-            if self._ensure_chain(active):
-                sampled, _ = self._inflight
-                host = jnp.asarray(eng._last_token.reshape(-1, 1))
-                fresh = jnp.asarray(eng._fresh)
-                old = self._inflight
-                self._dispatch(
-                    self._merge_toks(fresh, host, sampled), active
+            # 1) chunked-prefill burst per the interleave policy
+            if eng._chunking is not None:
+                decoding = (
+                    self._inflight is not None
+                    or any(s is not None for s in eng.slots)
                 )
-                self.stats["chained"] += 1
-                # the older tick lands while the new dispatch runs
-                sampled_old, act_old = old
-                eng._postprocess(np.asarray(sampled_old)[:, 0], act_old)
-                did = True
-            else:
-                # arena full: land (chain-safe held, so nothing
-                # completes), preempt through the SLA victim hook, and
-                # dispatch synchronously from host tokens.
+                cls = self.scheduler.classes.get(
+                    eng._chunking["req"].latency_class
+                )
+                steps = self.interleave.chunk_steps(
+                    decoding, cls.priority if cls is not None else None
+                )
+                for _ in range(steps):
+                    if eng._chunking is None:
+                        break
+                    eng._step_chunked()
+                    did = True
+
+            # 2) land BEFORE admission when the un-landed tick may complete
+            # a request — its freed slots then admit this very tick.
+            if self._inflight is not None and not self._chain_safe():
                 self._land_inflight()
-                active = np.array([s is not None for s in eng.slots])
-                eng._ensure_growth(active)
+                did = True
+
+            # 3) EDF admission from the scheduler's ready view
+            eng._admit(queue=self.scheduler.view(eng.clock()), chunk=False)
+            active = np.array([s is not None for s in eng.slots])
+
+            # 4) decode dispatch: chained (double-buffered) when safe
+            if self._inflight is not None:
+                if self._ensure_chain(active):
+                    old = self._inflight
+                    self._dispatch(active, chained=True)
+                    self.stats["chained"] += 1
+                    # the older tick lands while the new dispatch runs
+                    self._land(*old)
+                    did = True
+                else:
+                    # arena full: land (chain-safe held, so nothing
+                    # completes), preempt through the SLA victim hook, and
+                    # dispatch synchronously from host tokens.
+                    self._land_inflight()
+                    active = np.array([s is not None for s in eng.slots])
+                    eng._ensure_growth(active)
+                    if active.any():
+                        self._dispatch(active, chained=False)
+                        self.stats["host_dispatch"] += 1
+                        did = True
+            elif active.any():
+                if eng._paged:
+                    eng._ensure_growth(active)
                 if active.any():
-                    self._dispatch(
-                        jnp.asarray(eng._last_token.reshape(-1, 1)), active
-                    )
+                    self._dispatch(active, chained=False)
                     self.stats["host_dispatch"] += 1
                     did = True
-        elif active.any():
-            if eng._paged:
-                eng._ensure_growth(active)
-            if active.any():
-                self._dispatch(
-                    jnp.asarray(eng._last_token.reshape(-1, 1)), active
-                )
-                self.stats["host_dispatch"] += 1
-                did = True
 
-        # 5) stream landed tokens; refresh gauges
-        self._flush_streams()
-        self.stats["ticks"] += 1
-        depths = self.scheduler.depths()
-        eng.stats["queue_depth"] = depths
-        peak = eng.stats.setdefault("queue_depth_peak", {})
-        for name, depth in depths.items():
-            peak[name] = max(peak.get(name, 0), depth)
-        if did:
-            eng.tick_hist.record(max(eng.clock() - t0, 0.0))
+            # 5) stream landed tokens; refresh gauges
+            self._flush_streams()
+            self.stats["ticks"] += 1
+            depths = self.scheduler.depths()
+            eng.stats["queue_depth"] = depths
+            peak = eng.stats.setdefault("queue_depth_peak", {})
+            for name, depth in depths.items():
+                peak[name] = max(peak.get(name, 0), depth)
+            if did:
+                eng.tick_hist.record(max(eng.clock() - t0, 0.0))
         if sanitize.enabled():
             eng.compile_guard.assert_ok()
         return did
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.stream")
     def _flush_streams(self) -> None:
         """Push every landed-but-unstreamed token to its stream; close
         and retire streams whose requests completed."""
