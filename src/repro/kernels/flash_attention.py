@@ -434,9 +434,24 @@ def flash_attention(
 # the cache is viewed as ``(rows, tokens, KV * hd)`` (a free reshape of the
 # ``(.., KV, hd)`` layout), so the block's last two dims are
 # ``(block tokens, KV * hd)`` — the full lane extent, legal for Mosaic for
-# any head count.  The kernel walks the heads with static lane slices; per
-# head it runs the same fp32 online softmax as the forward kernel.  Per-
-# slot lengths (and block tables) ride as scalar-prefetch operands.
+# any head count.  Per-slot lengths (and block tables) ride as scalar-
+# prefetch operands.  The body takes one of two forms (``_all_heads_body``
+# picks by shape and format):
+#
+# * per head (GQA, quantized pools): the kernel walks the heads with
+#   static lane slices; per head it runs the same fp32 online softmax as
+#   the forward kernel, on ``(g, hd) . (hd, bk)`` dots.
+# * all heads (MHA, fp pools): one query row per KV head makes each head's
+#   dots ``(1, hd) . (hd, bk)`` — dozens of dependent chains of tiny ops a
+#   block, bound by latency.  Instead the query is laid out block-
+#   diagonally, ``Qsel[h, h*hd + d] = q[h, d]`` (built once per slot), so
+#   ONE ``(KV, KV*hd) . (KV*hd, bk)`` contraction gives every head's scores
+#   (heads on sublanes, the off-diagonal products exact zeros), the online
+#   softmax runs on all heads' rows at once, and ``p (KV, bk) . V (bk,
+#   KV*hd)`` accumulates into a ``(KV, KV*hd)`` fp32 scratch whose diagonal
+#   blocks are each head's output.  The products and fp32 accumulations are
+#   those of the per-head dots; finalize keeps the diagonal (a select and a
+#   sum of one value with zeros: exact).
 #
 # Quantized pools (nf4 / int8 codes + per-block fp32 scales) dequantize
 # each head's tile in VMEM.  NF4 packs element ``i`` in the high nibble
@@ -463,13 +478,44 @@ def _plane_scales(scales: jnp.ndarray, width: int, offset: int,
     return out
 
 
+# Cap on the all-heads body's fp32 working set, the ``(KV rows, KV * hd)``
+# accumulator and ``(KV rows, bk)`` scores: it grows with the square of
+# the head count, and so do the wasted off-diagonal products of its two
+# contractions.  Every MHA configuration here fits (32 heads of 128 at
+# bk = 512: 0.56 MiB).
+_ALL_HEADS_ACC_BYTES = 1 << 20
+
+
+def _head_rows(kv: int) -> int:
+    """KV heads padded to whole sublane tiles (8 rows)."""
+    return -(-kv // 8) * 8
+
+
+def _all_heads_body(kv: int, g: int, fmt: Optional[str], bk: int,
+                    width: int) -> bool:
+    """Whether the decode kernel attends all heads of a KV block in one
+    pass (see the section comment above) rather than head by head.
+
+    Only for one query row per KV head (``g == 1``: MHA) over an fp cache
+    (``fmt is None``; nf4 and int8 pools dequantize nibble planes per
+    head), and while the all-heads fp32 working set — the ``(rows,
+    width)`` accumulator and ``(rows, bk)`` scores — stays within
+    ``_ALL_HEADS_ACC_BYTES``.  A pure function of shapes and format, so
+    the serving engine can report the choice the kernel makes."""
+    if g != 1 or fmt is not None:
+        return False
+    return _head_rows(kv) * (width + bk) * 4 <= _ALL_HEADS_ACC_BYTES
+
+
 def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
                    kv: int, hd: int, scale: float, window: Optional[int],
-                   fmt: Optional[str], quant_block: int, value_dtype):
+                   fmt: Optional[str], quant_block: int, value_dtype,
+                   all_heads: bool):
     """One KV block of every head for one slot.  Operand order:
     ``prefetch..., q parts..., K refs..., V refs..., out parts...,
-    m, l, acc parts...`` — a "part" is the whole head_dim (fp, int8) or
-    one nibble plane's half of it (nf4)."""
+    m, l, acc parts...`` (+ ``qsel`` for the all-heads body) — a "part"
+    is the whole head_dim (fp, int8) or one nibble plane's half of it
+    (nf4)."""
     n_parts = 2 if fmt == "nf4" else 1
     n_kv_refs = 1 if fmt is None else 2          # codes (+ scales)
     len_ref = refs[n_prefetch - 1]
@@ -494,6 +540,13 @@ def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         for a in acc_refs:
             a[...] = jnp.zeros_like(a)
+        if all_heads:
+            qsel_ref = refs[-1]
+            qsel_ref[...] = jnp.where(
+                _diagonal(qsel_ref.shape, hd),
+                jnp.broadcast_to(q_refs[0][0].astype(jnp.float32),
+                                 qsel_ref.shape), 0.0,
+            )
 
     length = len_ref[b]                                    # this slot's len
     q_pos = length - 1
@@ -503,6 +556,55 @@ def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
     should = j * bk < length
     if window is not None:
         should &= (j + 1) * bk > q_pos - window + 1
+
+    def token_mask(shape):
+        """Keys of this block the query sees (length and window)."""
+        kv_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = kv_pos < length
+        if window is not None:
+            mask &= (q_pos - kv_pos) < window
+        return mask
+
+    def online_softmax(s, m_prev, l_prev):
+        """-> (p, alpha, m_new, l_new) for rows of scores ``s``."""
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        return p, alpha, m_new, l_new
+
+    if all_heads:
+        (q_ref,), (k_ref,), (v_ref,) = q_refs, k_refs, v_refs
+        (o_ref,), (acc_ref,), qsel_ref = o_refs, acc_refs, refs[-1]
+
+        @pl.when(should)
+        def _step_all():
+            s = jax.lax.dot_general(
+                qsel_ref[...].astype(q_ref.dtype), k_ref[0],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                      # (rows, bk)
+            s = jnp.where(token_mask(s.shape), s, MASK_VALUE)
+            p, alpha, m_new, l_new = online_softmax(
+                s, m_ref[:, :1], l_ref[:, :1]
+            )
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            v = v_ref[0]
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                              # (rows, width)
+
+        @pl.when(j == n_k - 1)
+        def _finalize_all():
+            denom = l_ref[:, :1]
+            denom = jnp.where(denom == 0.0, 1.0, denom)
+            out = jnp.where(_diagonal(acc_ref.shape, hd),
+                            acc_ref[...] / denom, 0.0)
+            o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+        return
 
     def head_parts(refs_, h):
         """The ``n_parts`` (bk, part width) value tiles of head ``h``."""
@@ -521,7 +623,7 @@ def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
 
     @pl.when(should)
     def _step():
-        kv_pos = None
+        mask = None
         for h in range(kv):
             ks = head_parts(k_refs, h)
             s = None
@@ -531,22 +633,12 @@ def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
                     preferred_element_type=jnp.float32,
                 )                                          # (G, bk)
                 s = part if s is None else s + part
-            s = s * scale
-            if kv_pos is None:
-                kv_pos = j * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1
-                )
-                mask = kv_pos < length
-                if window is not None:
-                    mask &= (q_pos - kv_pos) < window
-            s = jnp.where(mask, s, MASK_VALUE)
-
-            m_prev = m_ref[h][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_ref[h][:, :1] + jnp.sum(p, axis=-1,
-                                                       keepdims=True)
+            if mask is None:
+                mask = token_mask(s.shape)
+            s = jnp.where(mask, s * scale, MASK_VALUE)
+            p, alpha, m_new, l_new = online_softmax(
+                s, m_ref[h][:, :1], l_ref[h][:, :1]
+            )
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
             for acc_ref, v in zip(acc_refs, head_parts(v_refs, h)):
@@ -564,6 +656,14 @@ def _decode_kernel(*refs, n_prefetch: int, bk: int, n_k: int,
                 o_ref[0, h] = (acc_ref[h] / denom).astype(o_ref.dtype)
 
 
+def _diagonal(shape, hd: int) -> jnp.ndarray:
+    """``(rows, rows_kv * hd)`` mask: lane ``i`` of row ``h`` is head
+    ``h``'s (``h * hd <= i < (h + 1) * hd``)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * hd
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= row) & (lane < row + hd)
+
+
 def _decode_call(q, k_tiles, v_tiles, prefetch, *, block_map, bk, n_k,
                  kv, hd, scale, window, fmt, quant_block, value_dtype,
                  interpret, name):
@@ -575,15 +675,20 @@ def _decode_call(q, k_tiles, v_tiles, prefetch, *, block_map, bk, n_k,
     block."""
     b, _, h, _ = q.shape
     g = h // kv
-    qg = q.reshape(b, kv, g, hd)
-    if fmt == "nf4":
+    width = k_tiles[0].shape[2]
+    all_heads = _all_heads_body(kv, g, fmt, bk, width)
+    if all_heads:
+        # one (1, KV * hd) lane row a slot: the all-heads body's layout
+        q_parts = [q.reshape(b, 1, width)]
+    elif fmt == "nf4":
+        qg = q.reshape(b, kv, g, hd)
         q_parts = [qg[..., :hd // 2], qg[..., hd // 2:]]
     else:
-        q_parts = [qg]
+        q_parts = [q.reshape(b, kv, g, hd)]
     n_pre = len(prefetch)
 
     def q_map(b_, j, *pre):
-        return (b_, 0, 0, 0)
+        return (b_,) + (0,) * (q_parts[0].ndim - 1)
 
     def kv_map(b_, j, *pre):
         return (*block_map(b_, j, *pre), 0)
@@ -591,26 +696,38 @@ def _decode_call(q, k_tiles, v_tiles, prefetch, *, block_map, bk, n_k,
     in_specs = [pl.BlockSpec((1,) + p.shape[1:], q_map) for p in q_parts]
     for t in list(k_tiles) + list(v_tiles):
         in_specs.append(pl.BlockSpec((1, bk, t.shape[2]), kv_map))
-    part_hd = [p.shape[-1] for p in q_parts]
+    if all_heads:
+        rows = _head_rows(kv)
+        out_shapes = [(b, 1, width)]
+        scratch = [
+            pltpu.VMEM((rows, _STATS_LANES), jnp.float32),   # running max
+            pltpu.VMEM((rows, _STATS_LANES), jnp.float32),   # running denom
+            pltpu.VMEM((rows, width), jnp.float32),           # acc
+            # Qsel: q values, exact in fp32 (every scratch is fp32)
+            pltpu.VMEM((rows, width), jnp.float32),
+        ]
+    else:
+        out_shapes = [(b, kv, g, p.shape[-1]) for p in q_parts]
+        scratch = [
+            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running max
+            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running denom
+        ] + [pltpu.VMEM((kv, g, p.shape[-1]), jnp.float32) for p in q_parts]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre,
         grid=(b, n_k),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, kv, g, w), q_map) for w in part_hd],
-        scratch_shapes=[
-            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running max
-            pltpu.VMEM((kv, g, _STATS_LANES), jnp.float32),  # running denom
-        ] + [pltpu.VMEM((kv, g, w), jnp.float32) for w in part_hd],
+        out_specs=[pl.BlockSpec((1,) + o[1:], q_map) for o in out_shapes],
+        scratch_shapes=scratch,
     )
     outs = pl.pallas_call(
         functools.partial(
             _decode_kernel, n_prefetch=n_pre, bk=bk, n_k=n_k,
             kv=kv, hd=hd, scale=scale, window=window, fmt=fmt,
             quant_block=quant_block, value_dtype=value_dtype,
+            all_heads=all_heads,
         ),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, kv, g, w), q.dtype)
-                   for w in part_hd],
+        out_shape=[jax.ShapeDtypeStruct(o, q.dtype) for o in out_shapes],
         interpret=resolve_interpret(interpret),
         name=name,
     )(*prefetch, *q_parts, *k_tiles, *v_tiles)

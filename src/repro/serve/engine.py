@@ -237,6 +237,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import sys
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -247,6 +248,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import sanitize
+from repro.kernels.flash_attention import _all_heads_body
 from repro.models.common import (
     insert_cache_slots, merge_cache_slots, reset_cache_slots,
 )
@@ -529,6 +531,18 @@ class ServingEngine:
             # stats refresh keeps this in sync with the pool view)
             "kv_quant": self.kv_quant or "none",
         }
+        if self._paged and self.cfg.attn_backend == "pallas":
+            # the body the paged decode kernel compiles, decided by shapes
+            # and pool format alone (as the kernel decides it)
+            cfg = self.cfg
+            all_heads = _all_heads_body(
+                cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
+                self.pager.kv_quant, block_size,
+                cfg.n_kv_heads * cfg.head_dim,
+            )
+            body = "all_heads" if all_heads else "per_head"
+            self.stats["decode_attn_body"] = body
+            print(f"decode_attn_body: {body}", file=sys.stderr, flush=True)
 
         can_prefill = (
             hasattr(model, "prefill") and self.cfg.frontend is None

@@ -125,14 +125,22 @@ def test_mamba2_view_is_trivially_dense():
 
 
 # ------------------------------------------------- paged decode attention
-def test_paged_decode_attention_kernel_matches_gather():
-    b, h, kv, hd, bs, nb = 3, 8, 4, 16, 8, 8
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("h,kv,hd,bs", [
+    (8, 4, 16, 8),      # GQA: the per-head body
+    (6, 6, 64, 16),     # MHA, two heads a 128-lane vreg: the all-heads body
+])
+def test_paged_decode_attention_kernel_matches_gather(h, kv, hd, bs, dtype,
+                                                      tol):
+    nb = 8
+    lens = jnp.array([5, 37, 1, 16, 17, nb * bs], jnp.int32)
+    b = lens.shape[0]
     n_pool = b * nb + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, 1, h, hd))
-    k_pool = jax.random.normal(ks[1], (n_pool, bs, kv, hd))
-    v_pool = jax.random.normal(ks[2], (n_pool, bs, kv, hd))
-    lens = jnp.array([5, 37, 64], jnp.int32)
+    q = jax.random.normal(ks[0], (b, 1, h, hd)).astype(dtype)
+    k_pool = jax.random.normal(ks[1], (n_pool, bs, kv, hd)).astype(dtype)
+    v_pool = jax.random.normal(ks[2], (n_pool, bs, kv, hd)).astype(dtype)
     rng = np.random.default_rng(1)
     perm = rng.permutation(np.arange(1, n_pool))
     tables = np.zeros((b, nb), np.int32)
@@ -148,8 +156,28 @@ def test_paged_decode_attention_kernel_matches_gather():
                                      window=window)
         out = paged_decode_attention(q, k_pool, v_pool, tables, lens,
                                      window=window, backend="pallas")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=3e-5, atol=3e-5)
+        assert out.dtype == dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+def test_decode_attn_body_choice():
+    """The all-heads body engages for one query row per KV head over an
+    fp pool (minicpm-2b's paged serving shapes) and nowhere else."""
+    from repro.configs.minicpm_2b import FULL as MINICPM
+    from repro.configs.qwen2_0_5b import FULL as QWEN
+    from repro.kernels.flash_attention import _all_heads_body
+
+    def body(cfg, fmt, bk):
+        kv = cfg.n_kv_heads
+        return _all_heads_body(kv, cfg.n_heads // kv, fmt, bk,
+                               kv * cfg.head_dim)
+
+    assert body(MINICPM, None, 16)
+    assert not body(QWEN, None, 16)                 # 14 / 2 heads: GQA
+    for fmt in ("nf4", "int8"):
+        assert not body(MINICPM, fmt, 16)           # nibble / code planes
 
 
 # (the odd-max_len pad+slice decode fix is covered in test_attention.py:
@@ -515,6 +543,32 @@ def test_paged_quant_engine_pallas_backend_matches_reference():
             engine.submit(r)
         engine.run()
         outs[mode] = [r.output for r in reqs]
+    assert engine.stats["decode_attn_body"] == "per_head"
+    assert outs["paged"] == outs["dense"]
+
+
+@pytest.mark.parametrize("arch,body", [("minicpm-2b", "all_heads"),
+                                       ("qwen2-0.5b", "per_head")])
+def test_paged_pallas_engine_decode_body(arch, body, capfd):
+    """The paged Pallas engine (MHA: the all-heads body; GQA: per head)
+    serves what the dense reference engine serves, and reports and logs
+    the body its decode kernel runs."""
+    cfg = get_smoke(arch).replace(kv_block=16)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    prompts = [[5, 9, 13], [40, 2, 17, 3], [7] * 19]
+    outs = {}
+    for backend, mode in (("reference", "dense"), ("pallas", "paged")):
+        m = build_model(cfg.replace(attn_backend=backend))
+        engine = ServingEngine(m, params, n_slots=3, max_len=64,
+                               cache=mode, block_size=16)
+        reqs = [Request(uid=i, prompt=list(p), max_new_tokens=5)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            engine.submit(r)
+        engine.run()
+        outs[mode] = [r.output for r in reqs]
+    assert engine.stats["decode_attn_body"] == body
+    assert capfd.readouterr().err.count(f"decode_attn_body: {body}") == 1
     assert outs["paged"] == outs["dense"]
 
 

@@ -105,18 +105,24 @@ def test_paged_decode(one_chip, kv_quant):
     from repro.core.quantize import quantize_kv
     from repro.kernels.flash_attention import paged_flash_decode_attention
 
-    b, bs, n_pool, n_b = 8, 16, 512, 40
+    bs = 16
+    if kv_quant is None:
+        # a small pool, then the serving cell's own: 16 slots over 400
+        # pool rows, 161-entry tables (the all-heads body, VMEM included)
+        for b, n_pool, n_b in ((8, 512, 40), (16, 400, 161)):
+            pool = _spec((n_pool, bs, H, HD), jnp.bfloat16, one_chip)
+            _compiled_text(
+                lambda q, k, v, t, n: paged_flash_decode_attention(
+                    q, k, v, t, n, interpret=False),
+                _spec((b, 1, H, HD), jnp.bfloat16, one_chip), pool, pool,
+                _spec((b, n_b), jnp.int32, one_chip),
+                _spec((b,), jnp.int32, one_chip),
+            )
+        return
+    b, n_pool, n_b = 8, 512, 40
     q = _spec((b, 1, H, HD), jnp.bfloat16, one_chip)
     bt = _spec((b, n_b), jnp.int32, one_chip)
     lens = _spec((b,), jnp.int32, one_chip)
-    if kv_quant is None:
-        pool = _spec((n_pool, bs, H, HD), jnp.bfloat16, one_chip)
-        _compiled_text(
-            lambda q, k, v, t, n: paged_flash_decode_attention(
-                q, k, v, t, n, interpret=False),
-            q, pool, pool, bt, lens,
-        )
-        return
     codes, scales = jax.eval_shape(
         lambda x: quantize_kv(x, kv_quant, block_size=QB),
         jax.ShapeDtypeStruct((n_pool, bs, H, HD), jnp.bfloat16),
